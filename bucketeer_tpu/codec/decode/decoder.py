@@ -14,10 +14,10 @@ truncates every code-block at quality layer ``l``.
 from __future__ import annotations
 
 import struct
-import time
 
 import numpy as np
 
+from ... import obs
 from ..encoder import _ceil_div
 from ..pipeline import _band_geometry
 from . import device
@@ -41,7 +41,7 @@ def _tile_hvals(ps: parser.ParsedStream, tile: parser.DecTile,
                 reduce: int) -> tuple:
     """Tier-1 decode one tile's kept code-blocks and assemble them into
     (C, rh, rw) int32 half-magnitude Mallat planes. Returns
-    (planes, n_blocks, n_decisions, mq_seconds, asm_seconds)."""
+    (planes, n_blocks, n_decisions)."""
     levels_used = ps.levels - reduce
     rh, rw = _reduced_dims(tile.th, tile.tw, reduce)
     local = {}
@@ -70,17 +70,22 @@ def _tile_hvals(ps: parser.ParsedStream, tile: parser.DecTile,
                     places.append((c, ly0 + gy0 - band.by0,
                                    lx0 + gx0 - band.bx0))
 
-    t0 = time.perf_counter()
-    hvs, n_dec = t1_dec.decode_blocks(specs)
-    t_mq = time.perf_counter() - t0
+    hvs, n_dec = _mq_decode(specs)
+    with obs.stage(_metrics_sink, "decode.t1", rh * rw, len(specs)):
+        planes = np.zeros((ps.n_comps, rh, rw), dtype=np.int32)
+        for (c, y, x), hv in zip(places, hvs):
+            bh, bw = hv.shape
+            planes[c, y:y + bh, x:x + bw] = hv
+    return planes, len(specs), n_dec
 
-    t0 = time.perf_counter()
-    planes = np.zeros((ps.n_comps, rh, rw), dtype=np.int32)
-    for (c, y, x), hv in zip(places, hvs):
-        bh, bw = hv.shape
-        planes[c, y:y + bh, x:x + bw] = hv
-    t_asm = time.perf_counter() - t0
-    return planes, len(specs), n_dec, t_mq, t_asm
+
+def _mq_decode(specs: list) -> tuple:
+    """One tile's MQ decode: the ``decode.mq`` stage, items = MQ
+    decisions."""
+    with obs.stage(_metrics_sink, "decode.mq") as st:
+        hvs, n_dec = t1_dec.decode_blocks(specs)
+        st.items = n_dec
+    return hvs, n_dec
 
 
 def _reduced_dims(a: int, b: int, reduce: int) -> tuple:
@@ -146,7 +151,7 @@ def _tile_region_hvals(ps: parser.ParsedStream, tile: parser.DecTile,
                        reduce: int, plan: device.RegionPlan) -> tuple:
     """Tier-1 decode only the code-blocks intersecting the planned
     windows and assemble per-slot (C, bh, bw) window arrays. Returns
-    (arrays, n_blocks, n_decisions, mq_seconds, asm_seconds)."""
+    (arrays, n_blocks, n_decisions)."""
     levels_used = ps.levels - reduce
     rh, rw = _reduced_dims(tile.th, tile.tw, reduce)
     expected = {}
@@ -174,37 +179,33 @@ def _tile_region_hvals(ps: parser.ParsedStream, tile: parser.DecTile,
                               ly1 - ly0, lx1 - lx0))
                 places.append((si, c, ly0, ly1, lx0, lx1))
 
-    t0 = time.perf_counter()
-    hvs, n_dec = t1_dec.decode_blocks(specs)
-    t_mq = time.perf_counter() - t0
-
-    t0 = time.perf_counter()
-    for (si, c, ly0, ly1, lx0, lx1), hv in zip(places, hvs):
-        _, _, wy0, wy1, wx0, wx1, _ = plan.slots[si]
-        oy0, oy1 = max(ly0, wy0), min(ly1, wy1)
-        ox0, ox1 = max(lx0, wx0), min(lx1, wx1)
-        arrays[si][c, oy0 - wy0:oy1 - wy0, ox0 - wx0:ox1 - wx0] = \
-            hv[oy0 - ly0:oy1 - ly0, ox0 - lx0:ox1 - lx0]
-    t_asm = time.perf_counter() - t0
-    return arrays, len(specs), n_dec, t_mq, t_asm
+    hvs, n_dec = _mq_decode(specs)
+    with obs.stage(_metrics_sink, "decode.t1", items=len(specs)):
+        for (si, c, ly0, ly1, lx0, lx1), hv in zip(places, hvs):
+            _, _, wy0, wy1, wx0, wx1, _ = plan.slots[si]
+            oy0, oy1 = max(ly0, wy0), min(ly1, wy1)
+            ox0, ox1 = max(lx0, wx0), min(lx1, wx1)
+            arrays[si][c, oy0 - wy0:oy1 - wy0, ox0 - wx0:ox1 - wx0] = \
+                hv[oy0 - ly0:oy1 - ly0, ox0 - lx0:ox1 - lx0]
+    return arrays, len(specs), n_dec
 
 
 def _decode_region_impl(data: bytes, reduce: int, layers: int | None,
                         region, idx: sindex.StreamIndex | None):
-    t0 = time.perf_counter()
-    if idx is not None:
-        ps = sindex.skeleton(idx)
-        if reduce < 0:
-            raise InvalidParam(f"invalid reduce {reduce}")
-        if layers is not None and layers < 1:
-            raise InvalidParam(f"invalid layers {layers}")
-        if reduce > ps.levels:
-            raise InvalidParam(
-                f"reduce={reduce} exceeds {ps.levels} decomposition "
-                "levels")
-    else:
-        ps = parser.parse(data, reduce=reduce, layers=layers)
-    t_parse = time.perf_counter() - t0
+    with obs.stage(_metrics_sink, "decode.t2_parse") as st:
+        if idx is not None:
+            ps = sindex.skeleton(idx)
+            if reduce < 0:
+                raise InvalidParam(f"invalid reduce {reduce}")
+            if layers is not None and layers < 1:
+                raise InvalidParam(f"invalid layers {layers}")
+            if reduce > ps.levels:
+                raise InvalidParam(
+                    f"reduce={reduce} exceeds {ps.levels} decomposition "
+                    "levels")
+        else:
+            ps = parser.parse(data, reduce=reduce, layers=layers)
+        st.items = ps.n_packets
 
     levels_used = ps.levels - reduce
     ry0, ry1, rx0, rx1 = _map_region(region, ps.width, ps.height, reduce)
@@ -231,30 +232,27 @@ def _decode_region_impl(data: bytes, reduce: int, layers: int | None,
         work.append((tidx, (ty0, tx0), plan))
 
     if idx is not None:
-        t0 = time.perf_counter()
-        max_layers = ps.n_layers if layers is None else min(
-            layers, ps.n_layers)
-        sindex.parse_tiles(
-            data, idx, ps,
-            {tidx: _slot_windows(plan, levels_used)
-             for tidx, _, plan in work},
-            levels_used, max_layers)
-        t_parse += time.perf_counter() - t0
+        with obs.stage(_metrics_sink, "decode.t2_parse") as st:
+            n_before = ps.n_packets
+            max_layers = ps.n_layers if layers is None else min(
+                layers, ps.n_layers)
+            sindex.parse_tiles(
+                data, idx, ps,
+                {tidx: _slot_windows(plan, levels_used)
+                 for tidx, _, plan in work},
+                levels_used, max_layers)
+            st.items = ps.n_packets - n_before
 
     tiles_by_idx = {t.idx: t for t in ps.tiles}
     n_blocks = n_dec = 0
-    t_mq = t_asm = t_dev = 0.0
     for tidx, (ty0, tx0), plan in work:
         tile = tiles_by_idx[tidx]
-        arrays, nb, nd, tm, ta = _tile_region_hvals(ps, tile, reduce,
-                                                    plan)
+        arrays, nb, nd = _tile_region_hvals(ps, tile, reduce, plan)
         n_blocks += nb
         n_dec += nd
-        t_mq += tm
-        t_asm += ta
-        t0 = time.perf_counter()
-        tile_img = device.run_region_inverse(plan, arrays)
-        t_dev += time.perf_counter() - t0
+        with obs.stage(_metrics_sink, "decode.device_inverse") as st:
+            tile_img = device.run_region_inverse(plan, arrays)
+            st.pixels = tile_img.shape[0] * tile_img.shape[1]
         # The tile's window is [max(ry0-ty0,0), ...) tile-local; place
         # it back at its global reduced position inside the crop.
         oy = ty0 + max(ry0 - ty0, 0) - ry0
@@ -263,12 +261,6 @@ def _decode_region_impl(data: bytes, reduce: int, layers: int | None,
             ox:ox + tile_img.shape[1]] = tile_img
 
     if _metrics_sink is not None:
-        _metrics_sink.record("decode.t2_parse", t_parse,
-                             items=ps.n_packets)
-        _metrics_sink.record("decode.mq", t_mq, items=n_dec)
-        _metrics_sink.record("decode.t1", t_asm, items=n_blocks)
-        _metrics_sink.record("decode.device_inverse", t_dev,
-                             pixels=out.shape[0] * out.shape[1])
         _metrics_sink.count("decode.blocks", n_blocks)
         _metrics_sink.count("decode.region_blocks", n_blocks)
         _metrics_sink.count("decode.mq_symbols", n_dec)
@@ -282,53 +274,43 @@ def _decode_region_impl(data: bytes, reduce: int, layers: int | None,
 
 
 def _decode_impl(data: bytes, reduce: int, layers: int | None):
-    t0 = time.perf_counter()
-    ps = parser.parse(data, reduce=reduce, layers=layers)
-    t_parse = time.perf_counter() - t0
+    with obs.stage(_metrics_sink, "decode.t2_parse") as st:
+        ps = parser.parse(data, reduce=reduce, layers=layers)
+        st.pixels = ps.width * ps.height
+        st.items = ps.n_packets
 
     levels_used = ps.levels - reduce
     out_h, out_w = _reduced_dims(ps.height, ps.width, reduce)
     out = np.zeros((out_h, out_w, ps.n_comps), dtype=np.int32)
 
     n_blocks = n_dec = 0
-    t_mq = t_asm = 0.0
     groups: dict = {}         # (rh, rw) -> ([planes], [(ry0, rx0)])
     for tile in ps.tiles:
-        planes, nb, nd, tm, ta = _tile_hvals(ps, tile, reduce)
+        planes, nb, nd = _tile_hvals(ps, tile, reduce)
         n_blocks += nb
         n_dec += nd
-        t_mq += tm
-        t_asm += ta
         y0, x0 = tile.origin
         ry0, rx0 = _reduced_dims(y0, x0, reduce)
         key = planes.shape[1:]
         groups.setdefault(key, ([], []))[0].append(planes)
         groups[key][1].append((ry0, rx0))
 
-    t0 = time.perf_counter()
     for (rh, rw), (planes_list, origins) in groups.items():
         def delta_of(lvl, name, _lu=levels_used):
             res = 0 if name == "LL" else _lu - lvl + 1
             return ps.quants[(res, name)].delta
 
-        plan = device.make_inverse_plan(
-            rh, rw, ps.n_comps, levels_used, ps.reversible, ps.bitdepth,
-            ps.used_mct, delta_of)
-        batch = np.stack(planes_list)
-        samples = device.run_inverse(plan, batch)
-        for (ry0, rx0), tile_img in zip(origins, samples):
-            out[ry0:ry0 + rh, rx0:rx0 + rw] = tile_img
-    t_dev = time.perf_counter() - t0
+        with obs.stage(_metrics_sink, "decode.device_inverse",
+                       len(planes_list) * rh * rw):
+            plan = device.make_inverse_plan(
+                rh, rw, ps.n_comps, levels_used, ps.reversible,
+                ps.bitdepth, ps.used_mct, delta_of)
+            batch = np.stack(planes_list)
+            samples = device.run_inverse(plan, batch)
+            for (ry0, rx0), tile_img in zip(origins, samples):
+                out[ry0:ry0 + rh, rx0:rx0 + rw] = tile_img
 
     if _metrics_sink is not None:
-        px = ps.width * ps.height
-        _metrics_sink.record("decode.t2_parse", t_parse, pixels=px,
-                             items=ps.n_packets)
-        _metrics_sink.record("decode.mq", t_mq, items=n_dec)
-        _metrics_sink.record("decode.t1", t_asm, pixels=out_h * out_w,
-                             items=n_blocks)
-        _metrics_sink.record("decode.device_inverse", t_dev,
-                             pixels=out_h * out_w)
         _metrics_sink.count("decode.blocks", n_blocks)
         _metrics_sink.count("decode.mq_symbols", n_dec)
         if ps.n_packets_skipped:

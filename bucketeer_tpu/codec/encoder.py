@@ -736,6 +736,11 @@ class _Chunk:
     pending: object = None   # frontend.PendingFrontend while dispatched
     fres: object = None      # frontend.FrontendResult once resolved
 
+    @property
+    def pixels(self) -> int:
+        """Source pixels of the chunk's tiles (all of one shape)."""
+        return len(self.members) * self.plan.tile_h * self.plan.tile_w
+
 
 def _build_chunks(groups: dict, plans: dict, used_mct: bool, gains,
                   weight_of_slot: dict, norms) -> tuple:
@@ -909,37 +914,44 @@ def encode_array(img: np.ndarray, bitdepth: int = 8,
         _tm_add("device", time.perf_counter() - t0)
 
     def host_code(chunk: _Chunk, floors: np.ndarray, payload: np.ndarray,
-                  offsets: np.ndarray) -> list:
+                  offsets: np.ndarray, pixels: int) -> list:
         """Runs on the bounded worker; native Tier-1 releases the GIL,
         so this overlaps the caller's device dispatch/waits. Submitted
         through obs.bind so the pool thread re-enters the request's
-        trace context (host Tier-1 items show in the span tree)."""
-        t0 = time.perf_counter()
-        with obs.span("encode.host_t1", blocks=len(chunk.dests)):
+        trace context (host Tier-1 items show in the span tree). Each
+        chunk records encode.host_code as it finishes."""
+        with obs.stage(_metrics_sink, "encode.host_code", pixels,
+                       span_name="encode.host_t1",
+                       blocks=len(chunk.dests)) as st:
             blocks = t1_batch.encode_packed(payload, offsets,
                                             chunk.fres.nbps,
                                             floors, chunk.hs, chunk.ws,
-                                            chunk.bandnames)
+                                            chunk.bandnames,
+                                            sink=_metrics_sink)
             if not params.lossless:
                 _correct_distortions(blocks, chunk.fres)
-        _tm_add("host", time.perf_counter() - t0)
+        _tm_add("host", st.seconds)
         return blocks
 
-    def host_replay(chunk: _Chunk, streams) -> list:
+    def host_replay(chunk: _Chunk, streams, pixels: int) -> list:
         """The CX/D-mode host half: pure MQ replay of the device's
         symbol streams — no context modeling left on the host."""
-        t0 = time.perf_counter()
-        with obs.span("encode.mq_replay", blocks=len(chunk.dests)):
+        with obs.stage(_metrics_sink, "encode.host_code", pixels,
+                       span_name="encode.mq_replay",
+                       blocks=len(chunk.dests)) as st:
             blocks = t1_batch.encode_cxd(streams)
             if not params.lossless:
                 _correct_distortions(blocks, chunk.fres)
-        dt = time.perf_counter() - t0
-        _tm_add("host", dt)
-        _tm_add("mq", dt)
+        _tm_add("host", st.seconds)
+        _tm_add("mq", st.seconds)
         return blocks
 
     def fetch_and_submit(pool, chunk: _Chunk, floors: np.ndarray,
-                         futs: list, release_rows: bool) -> None:
+                         futs: list, release_rows: bool,
+                         first: bool) -> None:
+        """``first``: the chunk's first attempt, the one whose stages
+        count its source pixels (a floor rerun adds time, not pixels)."""
+        pixels = chunk.pixels if first else 0
         t0 = time.perf_counter()
         if use_mq:
             # Tier-1 never touches the host: the device runs CX/D and
@@ -973,7 +985,11 @@ def encode_array(img: np.ndarray, bitdepth: int = 8,
             if not params.lossless:
                 _correct_distortions(blocks, chunk.fres)
             # The whole host share: assembly + distortion correction.
-            _tm_add("host", res.host_s + time.perf_counter() - th0)
+            host_s = res.host_s + time.perf_counter() - th0
+            _tm_add("host", host_s)
+            if _metrics_sink is not None:
+                _metrics_sink.record("encode.host_code", host_s,
+                                     pixels=pixels)
             # No back-pressure check: nothing is in flight — every
             # entry this branch appends is already resolved.
             futs.append(_ImmediateResult(blocks))
@@ -992,9 +1008,10 @@ def encode_array(img: np.ndarray, bitdepth: int = 8,
             if release_rows:
                 chunk.fres.blocks = None    # free the HBM staging buffer
         else:
-            src, offsets = frontend.payload_plan(chunk.fres.nbps, floors,
-                                                 chunk.fres.layout.P)
-            payload = frontend.fetch_payload(chunk.fres, src)
+            with obs.stage(_metrics_sink, "encode.fetch_payload", pixels):
+                src, offsets = frontend.payload_plan(
+                    chunk.fres.nbps, floors, chunk.fres.layout.P)
+                payload = frontend.fetch_payload(chunk.fres, src)
             _tm_add("device", time.perf_counter() - t0)
             if release_rows:
                 chunk.fres.rows = None  # free the staging buffer in HBM
@@ -1007,10 +1024,10 @@ def encode_array(img: np.ndarray, bitdepth: int = 8,
         # rebind the request's trace context around the host-coding item.
         if use_cxd:
             futs.append(pool.submit(obs.bind(host_replay), chunk,
-                                    streams))
+                                    streams, pixels))
         else:
             futs.append(pool.submit(obs.bind(host_code), chunk, floors,
-                                    payload, offsets))
+                                    payload, offsets, pixels))
 
     def chunk_floors(margin: float) -> list:
         if target is None:
@@ -1061,12 +1078,13 @@ def encode_array(img: np.ndarray, bitdepth: int = 8,
                     resolve(c)
                     fetch_and_submit(pool, c, np.zeros(
                         c.fres.n_blocks, np.int32), futs,
-                        release_rows=True)
+                        release_rows=True, first=True)
             while staged:
                 c = staged.popleft()
                 resolve(c)
                 fetch_and_submit(pool, c, np.zeros(
-                    c.fres.n_blocks, np.int32), futs, release_rows=True)
+                    c.fres.n_blocks, np.int32), futs, release_rows=True,
+                    first=True)
             blocks_by_chunk = [f.result() for f in futs]
         else:
             # Rate-targeted: floors need global stats, so phase A
@@ -1085,7 +1103,8 @@ def encode_array(img: np.ndarray, bitdepth: int = 8,
                 futs = []
                 for chunk, floors in zip(chunks, floors_by_chunk):
                     fetch_and_submit(pool, chunk, floors, futs,
-                                     release_rows=False)
+                                     release_rows=False,
+                                     first=attempt == 0)
                 blocks_by_chunk = [f.result() for f in futs]
                 avail = sum(len(b.data) for blocks in blocks_by_chunk
                             for b in blocks)
@@ -1118,12 +1137,11 @@ def encode_array(img: np.ndarray, bitdepth: int = 8,
     if _metrics_sink is not None:
         _metrics_sink.record("encode.device_dispatch", tm["device"],
                              pixels=h * w)
-        _metrics_sink.record("encode.host_code", tm["host"], pixels=h * w)
         if use_mq:
             # Full-device Tier-1 segments: context modeling, the MQ
             # coder (items=bytes -> bytes/s), and their sum (items=
-            # symbols -> symbols/s). encode.host_code above is the
-            # whole host share (block assembly only).
+            # symbols -> symbols/s). encode.host_code, recorded per
+            # chunk, is the whole host share (block assembly only).
             _metrics_sink.record("encode.cxd_device", tm["cxd"],
                                  pixels=h * w)
             _metrics_sink.record("encode.mq_device", tm["mq_dev"],
@@ -1172,7 +1190,8 @@ def _finish(img: np.ndarray, params: EncodeParams, tile_records: list,
     """PCRD layer allocation + Tier-2 + codestream assembly, iterated a
     few times so the assembled file size (headers included) lands on the
     byte target."""
-    with obs.span("encode.tier2"):
+    with obs.stage(_metrics_sink, "encode.tier2",
+                   img.shape[0] * img.shape[1]):
         return _finish_spanned(img, params, tile_records, all_blocks,
                                block_weights, assign_index, qcd_values,
                                used_mct, bitdepth, n_comps, levels,

@@ -13,7 +13,7 @@ import threading
 
 import numpy as np
 
-from .. import native
+from .. import native, obs
 from ..analysis.contracts import contract
 from . import t1
 
@@ -97,11 +97,12 @@ def _collect(lib, handle, n: int) -> list:
 def encode_packed(payload: np.ndarray, offsets: np.ndarray,
                   nbps: np.ndarray, floors: np.ndarray,
                   hs: np.ndarray, ws: np.ndarray,
-                  bands: list) -> list:
+                  bands: list, sink=None) -> list:
     """Tier-1 over the device front-end's packed bitmap payload
     (codec/frontend.py): payload (R, 512) uint8 rows, offsets (n+1,)
     row offsets per block, per-block nbps/floors/dims and band names.
-    Returns [t1.CodedBlock] in block order."""
+    Returns [t1.CodedBlock] in block order. The native call alone is
+    the ``t1.native`` stage of ``sink`` (items = blocks)."""
     n = len(nbps)
     lib = native.load()
     cls = np.array([_BAND_CLS[b] for b in bands], dtype=np.int32)
@@ -116,10 +117,11 @@ def encode_packed(payload: np.ndarray, offsets: np.ndarray,
         ws_c = np.ascontiguousarray(ws, dtype=np.int32)
         threads = default_threads()
         _note_call("t1_encode_packed", n, threads)
-        handle = lib.t1_encode_packed(
-            n, payload.ctypes.data, offs.ctypes.data, nbps_c.ctypes.data,
-            floors_c.ctypes.data, hs_c.ctypes.data, ws_c.ctypes.data,
-            cls.ctypes.data, threads)
+        with obs.stage(sink, "t1.native", items=n):
+            handle = lib.t1_encode_packed(
+                n, payload.ctypes.data, offs.ctypes.data,
+                nbps_c.ctypes.data, floors_c.ctypes.data, hs_c.ctypes.data,
+                ws_c.ctypes.data, cls.ctypes.data, threads)
         return _collect(lib, handle, n)
     _note_python(n)
     out = []

@@ -15,6 +15,7 @@ import os
 from pathlib import Path
 
 from .. import obs
+from ..codec import encoder as codec_encoder
 from ..codec import tiff
 from ..codec.encoder import EncodeParams, encode_jp2
 from .base import Conversion, ConverterError, output_path
@@ -167,7 +168,10 @@ class TpuConverter:
         if not os.path.exists(source_path):
             raise ConverterError(f"source not found: {source_path}")
         try:
-            img, bitdepth = tiff.read_image(source_path)
+            with obs.stage(codec_encoder._metrics_sink,
+                           "convert.tiff_read") as st:
+                img, bitdepth = tiff.read_image(source_path)
+                st.pixels = img.shape[0] * img.shape[1]
         except Exception as exc:
             raise ConverterError(
                 f"cannot read {source_path}: {exc}") from exc

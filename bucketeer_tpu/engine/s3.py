@@ -38,7 +38,7 @@ from dataclasses import dataclass
 from .. import constants as c
 from .. import obs
 from .. import op
-from . import faults
+from . import faults, retry
 from .bus import MessageBus, Reply
 from .retry import CircuitBreaker
 from .store import Counters, UploadsMap
@@ -265,7 +265,10 @@ class S3UploadWorker:
             metadata[c.JOB_NAME] = job_name
         try:
             faults.point("s3.put", image_id=image_id, bucket=bucket)
-            with obs.span("s3.put", image_id=image_id, bucket=bucket):
+            # A stage, not a layer span: it only awaits, so it stays
+            # out of the profiler's trace (obs.LAYER_SPANS).
+            with obs.stage(retry._METRICS, "s3.put", image_id=image_id,
+                           bucket=bucket):
                 await self.client.put(bucket, image_id, file_path,
                                       metadata)
         except Exception as exc:

@@ -1253,26 +1253,12 @@ class EncodeScheduler:
 
         # The merged launch belongs to no single request: it gets an
         # unparented span *linked* to every request span whose chunks
-        # it batched, carrying occupancy and the graftcost-modeled
-        # cost so each launch is a measured-vs-modeled drift sample
-        # (the drift also lands as an encode.modeled_drift value).
+        # it batched, carrying occupancy.
         n_tiles = sum(j.n_tiles for j in group)
         attrs = {"occupancy": len(group), "tiles": n_tiles,
                  "mode": group[0].mode, "device_id": widx}
-        modeled = None
-        # The modeled cost feeds both the span attrs and the /metrics
-        # drift distribution — compute it whenever either consumer is
-        # live (a sink without tracing still wants calibration data).
-        if (obs.installed() or self._sink is not None) \
-                and group[0].mode == "rows":
-            modeled = obs_cost.modeled_launch_seconds(n_tiles)
-            if modeled is not None:
-                attrs["modeled_s"] = round(modeled[0], 6)
-                attrs["modeled_from"] = modeled[1]
         links = [j.ctx for j in group if j.ctx is not None]
-        failed = False
         completed = False
-        t0 = seam.monotonic()
         try:
             with obs.span("device.launch", ctx=None, links=links,
                           **attrs):
@@ -1296,7 +1282,6 @@ class EncodeScheduler:
         # delivered to every waiting request and re-raised there, so no
         # waiter hangs and nothing is swallowed.
         except Exception as exc:    # graftlint: disable=swallowed-exception
-            failed = True
             for j in group:
                 seam.write(j, "error")
                 j.error = exc
@@ -1306,14 +1291,6 @@ class EncodeScheduler:
                 self._sink.count(f"encode.device_launches.d{widx}")
                 self._sink.count("encode.batched_tiles", n_tiles)
                 self._sink.observe("encode.batch_occupancy", len(group))
-                # Drift samples come from completed launches only: a
-                # launch that died 5 ms in would otherwise read as
-                # "10x faster than modeled" and poison the calibration
-                # distribution.
-                if modeled is not None and modeled[0] > 0 and not failed:
-                    self._sink.observe(
-                        "encode.modeled_drift",
-                        (seam.monotonic() - t0) / modeled[0])
             for j in group:
                 # A fatally-interrupted launch (BaseException in
                 # flight) reached neither the result assignments nor

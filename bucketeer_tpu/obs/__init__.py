@@ -17,8 +17,10 @@ surface:
 - :class:`SloWatchdog` (:mod:`.slo`) — per-endpoint latency budgets
   feeding breach counters and flight dumps.
 - :mod:`.logctx` — every log record gains ``request_id``.
-- :mod:`.cost` — graftcost-modeled launch cost for the merged-launch
-  span's measured-vs-modeled drift attribute.
+- :func:`stage` — a span that also feeds its ``/metrics`` stage as it
+  closes: the one seam for a timed layer boundary.
+- :mod:`.cost` — graftcost-modeled stage costs for the scheduler's
+  pipeline mapper.
 
 docs/observability.md is the operator-facing walkthrough.
 """
@@ -26,10 +28,10 @@ from __future__ import annotations
 
 from . import cost, export, flight, logctx, slo  # noqa: F401
 from .slo import SloWatchdog  # noqa: F401
-from .trace import (Recorder, bind, current_context,  # noqa: F401
-                    current_request_id, get_recorder, install,
-                    installed, maybe_install, request_context, span,
-                    use_context)
+from .trace import (LAYER_SPANS, Recorder, bind,  # noqa: F401
+                    current_context, current_request_id, get_recorder,
+                    install, installed, maybe_install, request_context,
+                    span, stage, use_context)
 
 
 def chrome_trace(request_id):

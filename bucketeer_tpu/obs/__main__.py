@@ -4,7 +4,7 @@ Runs one real (tiny) encode through the cross-request scheduler with
 tracing on and writes the request's Chrome-trace JSON — the artifact
 the CI ``obs`` job uploads so a reviewer can drop a real span tree
 into chrome://tracing or ui.perfetto.dev without booting the server.
-``--synthetic`` skips JAX entirely (a hand-built span tree), for
+``--synthetic`` runs no JAX computation (a hand-built span tree), for
 environments without a working backend.
 """
 from __future__ import annotations

@@ -1,55 +1,15 @@
-"""graftcost-modeled cost for the merged launch span.
+"""graftcost-modeled stage costs for the scheduler's pipeline mapper.
 
-The launch span carries a ``modeled_s`` attribute next to its measured
-duration so every device launch is a measured-vs-modeled data point —
-the drift signal ROADMAP item 1 needs to tell "the kernel got faster"
-from "the model was wrong". The model is the checked-in manifest's
-cost fingerprint (``.graftaudit-manifest.json``, written by
-``--write-manifest``) for the front-end program, rooflined through
-:mod:`..analysis.graftcost`'s machine models and scaled linearly from
-the nearest canonical batch bucket — deliberately cheap (one JSON read
-per process, no lowering at serve time) and deliberately approximate
-(the manifest models canonical variants, not every tile shape).
+The front-end and fused-Tier-1 programs' cost fingerprints from the
+checked-in manifest (``.graftaudit-manifest.json``, written by
+``--write-manifest``), rooflined through :mod:`..analysis.graftcost`'s
+machine model of the live backend. Only their ratio matters to the
+mapper, so canonical-variant costs are enough.
 """
 from __future__ import annotations
 
 import json
-import threading
 from pathlib import Path
-
-_LOCK = threading.Lock()
-_CACHE: dict = {"loaded": False, "entries": None, "machine": None}
-
-
-def _pow2_at_least(n: int) -> int:
-    b = 1
-    while b < n:
-        b <<= 1
-    return b
-
-
-def _load_entries():
-    """[(program_key, bucket_B, cost_dict)] for front-end row programs,
-    from the manifest at the repo/package root. None when unreadable."""
-    manifest = (Path(__file__).resolve().parents[2]
-                / ".graftaudit-manifest.json")
-    try:
-        data = json.loads(manifest.read_text(encoding="utf-8"))
-    except (OSError, json.JSONDecodeError):
-        return None
-    entries = []
-    for key, rec in data.get("programs", {}).items():
-        if not key.startswith("frontend.rows/"):
-            continue
-        cost = rec.get("cost")
-        bucket = key.rsplit("/B", 1)[-1]
-        try:
-            b = int(bucket)
-        except ValueError:
-            continue
-        if cost:
-            entries.append((key, b, cost))
-    return entries or None
 
 
 def _machine():
@@ -61,28 +21,6 @@ def _machine():
     from ..analysis import graftcost
 
     return graftcost.machine_for_device_kind(jax.devices()[0].device_kind)
-
-
-def modeled_launch_seconds(n_tiles: int) -> tuple | None:
-    """(modeled seconds, source label) for a merged rows-mode front-end
-    launch of ``n_tiles`` tiles, or None when the manifest has no
-    front-end entry; an unknown device raises. Picks the manifest
-    entry with the nearest canonical bucket and scales the roofline
-    time by padded_tiles / bucket."""
-    with _LOCK:
-        if not _CACHE["loaded"]:
-            _CACHE["entries"] = _load_entries()
-            _CACHE["machine"] = _machine()
-            _CACHE["loaded"] = True
-        entries = _CACHE["entries"]
-        machine = _CACHE["machine"]
-    if not entries or n_tiles <= 0:
-        return None
-    padded = _pow2_at_least(n_tiles)
-    key, bucket, cost = min(
-        entries, key=lambda e: (abs(e[1] - padded), e[0]))
-    scaled = _roofline(cost, machine) * (padded / bucket)
-    return scaled, f"{key}@{machine.name}"
 
 
 def _roofline(cost: dict, machine) -> float:
@@ -99,9 +37,8 @@ def modeled_stage_costs() -> tuple | None:
     (``frontend.cxd/...``) and the Tier-1 stage the fused CX/D+MQ
     program (``cxdmq.fused/...``, non-pallas — the portable variant the
     CPU mesh actually runs); both are rooflined through the same
-    machine model as :func:`modeled_launch_seconds`. Absolute scale
-    cancels in the mapper's ratios, so canonical-variant costs are
-    exactly enough."""
+    machine model. Absolute scale cancels in the mapper's ratios, so
+    canonical-variant costs are exactly enough."""
     manifest = (Path(__file__).resolve().parents[2]
                 / ".graftaudit-manifest.json")
     try:
@@ -123,8 +60,3 @@ def modeled_stage_costs() -> tuple | None:
         return None
     return front, t1
 
-
-def reset_cache() -> None:
-    """Test seam: drop the memoized manifest/machine."""
-    with _LOCK:
-        _CACHE.update(loaded=False, entries=None, machine=None)

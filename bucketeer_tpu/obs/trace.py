@@ -6,10 +6,9 @@ bounded *per-thread* ring buffers. The design constraints, in order:
 
 - **Near-zero cost when disabled.** Every public entry checks the one
   module global ``_REC`` and returns a shared no-op — no allocation,
-  no context-var traffic, no lock. The overhead budget test
-  (tests/test_obs.py) pins this fast path: with no recorder installed
-  the whole span surface must cost well under 2% of the tier1_split
-  probe.
+  no context-var traffic, no lock. tests/test_obs.py pins this fast
+  path by what it does: with no recorder installed an encode creates
+  no span, ring or profiler annotation.
 - **Bounded memory always-on.** A ring holds the last
   ``BUCKETEER_TRACE_RING`` completed spans per thread (default 4096,
   ~a few hundred bytes each); older spans are overwritten, with the
@@ -23,6 +22,12 @@ bounded *per-thread* ring buffers. The design constraints, in order:
   shared-field accesses carry seam annotations — the
   ``span_ring_concurrency`` scenario races span begin/end against
   flight dumps across hundreds of interleavings.
+- **On the profiler's clock.** A span named in :data:`LAYER_SPANS` (a
+  layer's own host work) also opens a ``jax.profiler.TraceAnnotation``
+  for its extent, so a device trace names its idle gaps by the
+  program's layers. Spans that enclose a whole request, or only await,
+  stay out: each overlaps every gap inside it and would name them all.
+  Without an active profiler an annotation costs one activity check.
 
 Context propagation rules (docs/observability.md has the full table):
 
@@ -44,10 +49,30 @@ import contextvars
 import itertools
 import os
 import threading
+import time
 
 from ..analysis.graftrace import seam
 
 DEFAULT_RING_SPANS = 4096
+
+# Spans that mark one layer's host work, and so enter the profiler's
+# trace (module docstring). Request-enclosing spans (http.*,
+# convert.encode, batch.item, decode.read, batchread.item, *.request)
+# and spans that only await (s3.put) are deliberately absent.
+LAYER_SPANS = frozenset({
+    "encode.queue_wait", "decode.queue_wait", "device.launch",
+    "encode.dispatch", "encode.resolve_stats", "encode.fetch_payload",
+    "encode.host_t1", "t1.native", "encode.tier2", "convert.tiff_read",
+    "decode.t2_parse", "decode.mq", "decode.device_inverse",
+})
+
+
+def _annotate(name: str):
+    """A profiler annotation for a layer span (JAX imported on first
+    use, so tracing without a layer span never loads it)."""
+    from jax.profiler import TraceAnnotation
+    return TraceAnnotation(name)
+
 
 # The current trace context: (trace_id, span_id | None). Module-level so
 # the fast path is one ContextVar.get; never mutated except via token
@@ -93,14 +118,19 @@ def maybe_install():
 
 
 class _Noop:
-    """The disabled-path span handle: one shared stateless instance."""
+    """The disabled-path span and stage handle: one shared stateless
+    instance, so setting ``pixels``/``items`` on it is a no-op."""
     __slots__ = ()
+    seconds = 0.0
 
     def __enter__(self):
-        return None
+        return self
 
     def __exit__(self, *exc):
         return False
+
+    def __setattr__(self, name, value):
+        pass
 
 
 _NOOP = _Noop()
@@ -144,19 +174,25 @@ class Span:
 
 
 class _SpanHandle:
-    """Enabled-path context manager for one span."""
+    """Enabled-path context manager for one span; a layer span also
+    holds its profiler annotation."""
 
-    __slots__ = ("_rec", "_span", "_token")
+    __slots__ = ("_rec", "_span", "_token", "_ann")
 
-    def __init__(self, rec, span, token):
+    def __init__(self, rec, span, token, ann):
         self._rec = rec
         self._span = span
         self._token = token
+        self._ann = ann
 
     def __enter__(self):
+        if self._ann is not None:
+            self._ann.__enter__()
         return self._span
 
     def __exit__(self, etype, exc, tb):
+        if self._ann is not None:
+            self._ann.__exit__(etype, exc, tb)
         s = self._span
         s.dur = seam.monotonic() - s.t0
         if etype is not None:
@@ -255,7 +291,8 @@ class Recorder:
                  seam.monotonic(), threading.current_thread().name,
                  attrs, tuple(links))
         token = _CTX.set((trace_id, s.span_id))
-        return _SpanHandle(self, s, token)
+        ann = _annotate(name) if name in LAYER_SPANS else None
+        return _SpanHandle(self, s, token, ann)
 
     def _finish(self, span: Span) -> None:
         self._ring().append(span)
@@ -322,6 +359,63 @@ def span(name: str, ctx=_UNSET, links=(), **attrs):
     if rec is None:
         return _NOOP
     return rec.start(name, ctx, links, attrs)
+
+
+class _Stage:
+    """One timed unit of a layer's work: a span while it runs, one
+    ``sink.record`` as it closes. ``pixels`` and ``items`` may be set
+    inside the block, once the work has counted them; ``seconds`` holds
+    the duration afterwards."""
+
+    __slots__ = ("pixels", "items", "seconds", "_rec", "_sink", "_stage",
+                 "_span", "_attrs", "_handle", "_t0")
+
+    def __init__(self, rec, sink, stage_name, span_name, pixels, items,
+                 attrs):
+        self.pixels = pixels
+        self.items = items
+        self.seconds = 0.0
+        self._rec = rec
+        self._sink = sink
+        self._stage = stage_name
+        self._span = span_name
+        self._attrs = attrs
+        self._handle = None
+        self._t0 = 0.0
+
+    def __enter__(self):
+        if self._rec is not None:
+            self._handle = self._rec.start(self._span, _UNSET, (),
+                                           self._attrs)
+            self._handle.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, etype, exc, tb):
+        self.seconds = time.perf_counter() - self._t0
+        try:
+            if self._sink is not None:
+                self._sink.record(self._stage, self.seconds, self.pixels,
+                                  self.items)
+        finally:
+            if self._handle is not None:
+                self._handle.__exit__(etype, exc, tb)
+        return False
+
+
+def stage(sink, name: str, pixels: int = 0, items: int = 0, *,
+          span_name: str | None = None, **attrs):
+    """Time a block as the span ``span_name`` (default ``name``) and, as
+    it closes, record it into ``sink``'s ``/metrics`` stage ``name``
+    (``sink.record(name, seconds, pixels, items)``; None records
+    nothing). The one seam for a timed layer boundary: no timer beside
+    the span. The shared no-op when there is neither a recorder nor a
+    sink."""
+    rec = _REC
+    if rec is None and sink is None:
+        return _NOOP
+    return _Stage(rec, sink, name, span_name or name, pixels, items,
+                  attrs)
 
 
 def current_context():

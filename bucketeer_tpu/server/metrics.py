@@ -5,7 +5,6 @@ New relative to the reference — it has no metrics endpoint (SURVEY.md §5:
 stage because throughput is the product metric."""
 from __future__ import annotations
 
-import contextlib
 import logging
 import math
 import re
@@ -196,17 +195,11 @@ class Metrics:
     # its own locks and must not nest under ours.
     _reporters: dict = field(default_factory=dict, repr=False)
 
-    @contextlib.contextmanager
     def time(self, stage: str, pixels: int = 0):
         # Every timed stage is also a graftscope span (no-op without a
         # recorder): the existing stage instrumentation across the
         # codec/engine IS the span tree's interior, one seam for both.
-        t0 = time.perf_counter()
-        with obs.span(stage):
-            try:
-                yield
-            finally:
-                self.record(stage, time.perf_counter() - t0, pixels)
+        return obs.stage(self, stage, pixels)
 
     def record(self, stage: str, seconds: float, pixels: int = 0,
                items: int = 0) -> None:

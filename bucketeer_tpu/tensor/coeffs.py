@@ -38,13 +38,13 @@ from __future__ import annotations
 
 import struct
 import threading
-import time
 from contextlib import contextmanager
 from dataclasses import dataclass
 from functools import lru_cache
 
 import numpy as np
 
+from .. import obs
 from ..analysis import retrace
 from ..codec.decode import decoder as decoder_mod
 from ..codec.decode import index as sindex
@@ -308,9 +308,9 @@ class _CoeffPlan:
 # --- the public entry -----------------------------------------------------
 
 def _full_impl(data: bytes, reduce: int, layers) -> CoefficientSet:
-    t0 = time.perf_counter()
-    ps = parser.parse(data, reduce=reduce, layers=layers)
-    t_parse = time.perf_counter() - t0
+    with obs.stage(decoder_mod._metrics_sink, "decode.t2_parse") as st:
+        ps = parser.parse(data, reduce=reduce, layers=layers)
+        st.items = ps.n_packets
     levels = ps.levels - reduce
     offs, dims = _grid_extents(ps, reduce, levels)
     keys = band_keys(levels)
@@ -319,13 +319,11 @@ def _full_impl(data: bytes, reduce: int, layers) -> CoefficientSet:
 
     n_tx = _tile_grid(ps)[1]
     n_blocks = n_dec = 0
-    t_mq = 0.0
     for tile in ps.tiles:
         _poll()
-        hv, nb, nd, tm, _ = decoder_mod._tile_hvals(ps, tile, reduce)
+        hv, nb, nd = decoder_mod._tile_hvals(ps, tile, reduce)
         n_blocks += nb
         n_dec += nd
-        t_mq += tm
         ty, tx = divmod(tile.idx, n_tx)
         rh, rw = hv.shape[1:]
         bd = _band_dims(rh, rw, levels)
@@ -337,12 +335,11 @@ def _full_impl(data: bytes, reduce: int, layers) -> CoefficientSet:
                 hv[:, y0:y0 + bh, x0:x0 + bw]
 
     deltas = {key: float(ps.quants[key].delta) for key in keys}
-    t0 = time.perf_counter()
-    out = _run_dequant(ps.reversible,
-                       tuple(deltas[k] for k in keys),
-                       [planes[k] for k in keys])
-    t_dq = time.perf_counter() - t0
-    _record(ps, t_parse, t_mq, t_dq, n_blocks, n_dec, region=False)
+    with obs.stage(decoder_mod._metrics_sink, "decode.coeff_dequant"):
+        out = _run_dequant(ps.reversible,
+                           tuple(deltas[k] for k in keys),
+                           [planes[k] for k in keys])
+    _count(ps, n_blocks, n_dec, region=False)
     return CoefficientSet(
         ps.width, ps.height, ps.n_comps, ps.bitdepth, levels, reduce,
         ps.reversible, ps.used_mct, dict(zip(keys, out)), deltas)
@@ -350,20 +347,20 @@ def _full_impl(data: bytes, reduce: int, layers) -> CoefficientSet:
 
 def _region_impl(data: bytes, reduce: int, layers, region,
                  idx) -> CoefficientSet:
-    t0 = time.perf_counter()
-    if idx is not None:
-        ps = sindex.skeleton(idx)
-        if reduce < 0:
-            raise InvalidParam(f"invalid reduce {reduce}")
-        if layers is not None and layers < 1:
-            raise InvalidParam(f"invalid layers {layers}")
-        if reduce > ps.levels:
-            raise InvalidParam(
-                f"reduce={reduce} exceeds {ps.levels} decomposition "
-                "levels")
-    else:
-        ps = parser.parse(data, reduce=reduce, layers=layers)
-    t_parse = time.perf_counter() - t0
+    with obs.stage(decoder_mod._metrics_sink, "decode.t2_parse") as st:
+        if idx is not None:
+            ps = sindex.skeleton(idx)
+            if reduce < 0:
+                raise InvalidParam(f"invalid reduce {reduce}")
+            if layers is not None and layers < 1:
+                raise InvalidParam(f"invalid layers {layers}")
+            if reduce > ps.levels:
+                raise InvalidParam(
+                    f"reduce={reduce} exceeds {ps.levels} decomposition "
+                    "levels")
+        else:
+            ps = parser.parse(data, reduce=reduce, layers=layers)
+        st.items = ps.n_packets
 
     levels = ps.levels - reduce
     ry0, ry1, rx0, rx1 = decoder_mod._map_region(
@@ -404,15 +401,17 @@ def _region_impl(data: bytes, reduce: int, layers, region,
                      _CoeffPlan(tuple(slots)), wins))
 
     if idx is not None:
-        t0 = time.perf_counter()
-        max_layers = ps.n_layers if layers is None else min(
-            layers, ps.n_layers)
-        sindex.parse_tiles(
-            data, idx, ps,
-            {tidx: decoder_mod._slot_windows(plan, levels)
-             for tidx, _, plan, _ in work},
-            levels, max_layers)
-        t_parse += time.perf_counter() - t0
+        with obs.stage(decoder_mod._metrics_sink,
+                       "decode.t2_parse") as st:
+            n_before = ps.n_packets
+            max_layers = ps.n_layers if layers is None else min(
+                layers, ps.n_layers)
+            sindex.parse_tiles(
+                data, idx, ps,
+                {tidx: decoder_mod._slot_windows(plan, levels)
+                 for tidx, _, plan, _ in work},
+                levels, max_layers)
+            st.items = ps.n_packets - n_before
 
     # Output window rectangles on the global band planes, from the
     # participating tiles' windows (adjacent tiles' windows abut, so
@@ -438,14 +437,12 @@ def _region_impl(data: bytes, reduce: int, layers, region,
                             dtype=np.int32) for key in keys}
     tiles_by_idx = {t.idx: t for t in ps.tiles}
     n_blocks = n_dec = 0
-    t_mq = 0.0
     for tidx, (ty, tx), plan, wins in work:
         _poll()
-        arrays, nb, nd, tm, _ = decoder_mod._tile_region_hvals(
+        arrays, nb, nd = decoder_mod._tile_region_hvals(
             ps, tiles_by_idx[tidx], reduce, plan)
         n_blocks += nb
         n_dec += nd
-        t_mq += tm
         # Slot order is details (res 1..L) then LL; re-key and place.
         slot_keys = [(res, name) for res in range(1, levels + 1)
                      for name in ("HL", "LH", "HH")] + [(0, "LL")]
@@ -458,26 +455,21 @@ def _region_impl(data: bytes, reduce: int, layers, region,
                         ox:ox + (bx1 - bx0)] = arr
 
     deltas = {key: float(ps.quants[key].delta) for key in keys}
-    t0 = time.perf_counter()
-    out = _run_dequant(ps.reversible,
-                       tuple(deltas[k] for k in keys),
-                       [planes[k] for k in keys])
-    t_dq = time.perf_counter() - t0
-    _record(ps, t_parse, t_mq, t_dq, n_blocks, n_dec, region=True)
+    with obs.stage(decoder_mod._metrics_sink, "decode.coeff_dequant"):
+        out = _run_dequant(ps.reversible,
+                           tuple(deltas[k] for k in keys),
+                           [planes[k] for k in keys])
+    _count(ps, n_blocks, n_dec, region=True)
     return CoefficientSet(
         ps.width, ps.height, ps.n_comps, ps.bitdepth, levels, reduce,
         ps.reversible, ps.used_mct, dict(zip(keys, out)), deltas,
         region=tuple(int(v) for v in region), windows=out_win)
 
 
-def _record(ps, t_parse, t_mq, t_dq, n_blocks, n_dec,
-            region: bool) -> None:
+def _count(ps, n_blocks, n_dec, region: bool) -> None:
     sink = decoder_mod._metrics_sink
     if sink is None:
         return
-    sink.record("decode.t2_parse", t_parse, items=ps.n_packets)
-    sink.record("decode.mq", t_mq, items=n_dec)
-    sink.record("decode.coeff_dequant", t_dq)
     sink.count("decode.coeff_requests")
     sink.count("decode.blocks", n_blocks)
     sink.count("decode.mq_symbols", n_dec)

@@ -1,15 +1,14 @@
 """graftscope core (bucketeer_tpu/obs): the span tracer's no-op fast
-path and overhead budget, ring accounting, context propagation
+path, ring accounting, context propagation
 (threads, bind), the flight recorder's dumps and rate limiting,
 Chrome-trace export validity, log-record correlation, the SLO
-watchdog, the modeled launch cost, the histogram math behind the new
+watchdog, the modeled stage costs, the histogram math behind the new
 server-side percentiles, and the Prometheus exposition round-trip."""
 import json
 import logging
 import math
 import re
 import threading
-import time
 
 import numpy as np
 import pytest
@@ -44,7 +43,7 @@ def no_recorder():
         obs.install(prev)
 
 
-# --- disabled fast path + overhead budget --------------------------------
+# --- disabled fast path -----------------------------------------------------
 
 def test_noop_fast_path_is_pinned(no_recorder):
     """With no recorder, span() returns the one shared no-op object —
@@ -53,42 +52,67 @@ def test_noop_fast_path_is_pinned(no_recorder):
     handle = obs.span("anything", attr=1)
     assert handle is _NOOP
     with handle as s:
-        assert s is None
+        assert s is _NOOP
     assert obs.current_context() is None
     # bind() must be the identity when disabled.
     fn = lambda: 7  # noqa: E731
     assert obs.bind(fn) is fn
 
 
-def test_overhead_budget_vs_tier1_split_probe(no_recorder):
-    """ISSUE 14 budget: with tracing disabled, the whole graftscope
-    surface must cost <2% of the tier1_split probe. A small encode has
-    well under 500 span-surface calls (a handful per chunk plus the
-    scheduler/metrics seams); 500x the measured per-call no-op cost
-    must fit the 2% budget of the same encode measured here."""
+def test_overhead_budget_vs_tier1_split_probe(no_recorder, monkeypatch):
+    """With tracing disabled the span surface does nothing, pinned by
+    what it does rather than by a wall-clock budget (which a loaded box
+    fails): through a small encode and decode every span and stage
+    handle is the shared _NOOP, and no span, ring or profiler
+    annotation is ever created."""
     from bucketeer_tpu.codec import encoder
+    from bucketeer_tpu.codec.decode import decode
+    from bucketeer_tpu.codec.decode import decoder
+    from bucketeer_tpu.obs import trace
+
+    created = []
+
+    class SpySpan(trace.Span):
+        __slots__ = ()
+
+        def __init__(self, *args, **kwargs):
+            created.append("span")
+            super().__init__(*args, **kwargs)
+
+    class SpyRing(trace._Ring):
+        __slots__ = ()
+
+        def __init__(self, *args, **kwargs):
+            created.append("ring")
+            super().__init__(*args, **kwargs)
+
+    handles = []
+
+    def spy(fn):
+        def wrapped(*args, **kwargs):
+            handles.append(fn(*args, **kwargs))
+            return handles[-1]
+        return wrapped
+
+    monkeypatch.setattr(trace, "Span", SpySpan)
+    monkeypatch.setattr(trace, "_Ring", SpyRing)
+    monkeypatch.setattr(trace, "_annotate",
+                        lambda name: created.append(name))
+    monkeypatch.setattr(obs, "span", spy(obs.span))
+    monkeypatch.setattr(obs, "stage", spy(obs.stage))
+    monkeypatch.setattr(encoder, "_metrics_sink", None)
+    monkeypatch.setattr(decoder, "_metrics_sink", None)
 
     assert obs.get_recorder() is None
     img = np.linspace(0, 255, 128 * 128 * 3).reshape(
         128, 128, 3).astype(np.uint8)
-    params = encoder.EncodeParams(lossless=True, levels=2)
-    encoder.encode_array(img, 8, params)          # warm the compiles
-    t0 = time.perf_counter()
-    encoder.encode_array(img, 8, params)
-    encode_s = time.perf_counter() - t0
-
-    n = 20000
-    t0 = time.perf_counter()
-    for _ in range(n):
-        with obs.span("probe", x=1):
-            pass
-    per_call = (time.perf_counter() - t0) / n
-
-    budget = 0.02 * encode_s
-    assert 500 * per_call < budget, (
-        f"disabled-span cost {per_call * 1e9:.0f} ns/call; 500 calls "
-        f"= {500 * per_call * 1e3:.3f} ms > 2% probe budget "
-        f"{budget * 1e3:.3f} ms")
+    data = encoder.encode_jp2(img, 8, encoder.EncodeParams(
+        lossless=True, levels=2))
+    np.testing.assert_array_equal(decode(data, region=(8, 8, 64, 64)),
+                                  img[8:72, 8:72])
+    assert handles, "the encode and decode opened no span at all"
+    assert all(h is _NOOP for h in handles)
+    assert created == []
 
 
 # --- enabled tracing ------------------------------------------------------
@@ -304,26 +328,13 @@ def test_slo_breach_counts_and_dumps_flight(recorder):
     assert watchdog.observe("other", 99.0) is False
 
 
-# --- modeled launch cost --------------------------------------------------
-
-def test_modeled_launch_seconds_from_manifest():
-    obs_cost.reset_cache()
-    modeled = obs_cost.modeled_launch_seconds(2)
-    assert modeled is not None, "repo manifest should provide a model"
-    seconds, source = modeled
-    assert seconds > 0
-    assert source.startswith("frontend.rows/")
-    # Linear bucket scaling: 4x the tiles ~ 2x the 2-tile estimate
-    # when the nearest bucket stays the same family.
-    more, _ = obs_cost.modeled_launch_seconds(8)
-    assert more > seconds
-    assert obs_cost.modeled_launch_seconds(0) is None
-
+# --- modeled stage costs ---------------------------------------------------
 
 def test_unknown_device_kind_raises_in_cost_model(monkeypatch):
     """The machine model is keyed by the device_kind JAX reports: a
     v5e gets its own entry, and an unknown accelerator raises when a
-    modeled cost is asked for instead of borrowing another chip's."""
+    modeled stage cost is asked for instead of borrowing another
+    chip's."""
     import types
 
     import jax
@@ -337,12 +348,8 @@ def test_unknown_device_kind_raises_in_cost_model(monkeypatch):
     with monkeypatch.context() as m:
         m.setattr(jax, "devices",
                   lambda: [types.SimpleNamespace(device_kind="TPU v99")])
-        obs_cost.reset_cache()
-        with pytest.raises(graftcost.UnknownDevice):
-            obs_cost.modeled_launch_seconds(2)
         with pytest.raises(graftcost.UnknownDevice):
             obs_cost.modeled_stage_costs()
-    obs_cost.reset_cache()
 
 
 # --- histogram math -------------------------------------------------------

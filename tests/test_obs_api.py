@@ -262,11 +262,6 @@ def test_merged_launch_span_links_both_requests():
             assert launch["attrs"]["tiles"] == 2
             assert launch["attrs"]["mode"] == "rows"
             assert launch["attrs"]["device_id"] == 0
-            # graftcost-modeled cost beside the measured duration —
-            # the per-launch measured-vs-modeled drift sample.
-            assert launch["attrs"]["modeled_s"] > 0
-            assert launch["attrs"]["modeled_from"].startswith(
-                "frontend.rows/")
             assert launch["dur"] >= 0
             # Both requests got sliced views of the one merged launch.
             assert {type(r) for r in results.values()} == {
