@@ -4,7 +4,8 @@ loop):
 
 - ``parser``   Tier-2: JP2 boxes, markers, packet headers (host)
 - ``index``    code-block-addressable stream index (random access)
-- ``t1_dec``   MQ + EBCOT context-modeling pass decode (host)
+- ``t1_dec``   MQ + EBCOT context-modeling pass decode (host; native
+               ``t1_decode_blocks``, Python reference and fallback)
 - ``device``   dequantize + inverse DWT + inverse RCT/ICT (jitted)
 - ``decoder``  orchestration, partial decode (``reduce`` / ``layers``),
                windowed region decode (``region`` / ``index``)

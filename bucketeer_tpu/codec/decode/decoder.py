@@ -81,10 +81,15 @@ def _tile_hvals(ps: parser.ParsedStream, tile: parser.DecTile,
 
 def _mq_decode(specs: list) -> tuple:
     """One tile's MQ decode: the ``decode.mq`` stage, items = MQ
-    decisions."""
+    decisions. Its blocks count in ``decode.native_blocks`` or, where
+    the native decoder is unavailable, ``decode.python_blocks``."""
     with obs.stage(_metrics_sink, "decode.mq") as st:
-        hvs, n_dec = t1_dec.decode_blocks(specs)
+        hvs, n_dec, on_native = t1_dec.decode_blocks(specs)
         st.items = n_dec
+    if _metrics_sink is not None:
+        n = len(specs)
+        _metrics_sink.count("decode.native_blocks", n if on_native else 0)
+        _metrics_sink.count("decode.python_blocks", 0 if on_native else n)
     return hvs, n_dec
 
 

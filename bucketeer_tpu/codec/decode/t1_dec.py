@@ -20,10 +20,16 @@ coefficient as ``|hval| >> 1``; a truncated (quality-layer) decode keeps
 the same half-step midpoint OpenJPEG reconstructs, which is what makes
 the lossy differential tests line up.
 
-Hot-loop engineering: flat Python lists (cheaper scalar indexing than
-numpy), incremental neighbor-significance counters updated only on the
-rare became-significant events, and context tables flattened to 1-D.
-Code-blocks are independent; ``decode_blocks`` is the batch entry.
+Code-blocks are independent; ``decode_blocks`` is the batch entry. It
+decodes one tile's blocks in one call to the native decoder
+(``native/t1.cpp`` ``t1_decode_blocks``), which releases the GIL and
+fans the blocks out over the encoder's thread pool, so concurrent reads
+decode in parallel. ``decode_block`` is the pure-Python reference the
+native decoder matches bit for bit; ``decode_blocks`` falls back to it
+only when the native library is unavailable. Its hot loop uses flat
+Python lists (cheaper scalar indexing than numpy), incremental
+neighbor-significance counters updated only on the rare
+became-significant events, and context tables flattened to 1-D.
 """
 from __future__ import annotations
 
@@ -32,8 +38,10 @@ import threading
 
 import numpy as np
 
+from ... import native
+from .. import t1_batch
 from ..mq import CTX_RL, CTX_UNIFORM, MQDecoder
-from ..t1 import _SC, _ZC_HH, _ZC_LL_LH
+from ..t1 import _SC, _ZC_HH, _ZC_LL_LH, BAND_CLS
 from .errors import DecodeError
 
 _services = threading.local()
@@ -103,11 +111,9 @@ def decode_block(data: bytes, nbps: int, npasses: int, band: str,
     if nbps <= 0 or npasses <= 0:
         return np.zeros((h, w), dtype=np.int32), 0
     if nbps > 30:
-        raise DecodeError(f"{nbps} bit-planes exceeds the 30-plane cap")
+        raise _error(_TOO_MANY_PLANES, nbps, npasses)
     if npasses > max_passes(nbps):
-        raise DecodeError(
-            f"{npasses} passes exceeds the {max_passes(nbps)} possible "
-            f"for {nbps} bit-planes")
+        raise _error(_TOO_MANY_PASSES, nbps, npasses)
 
     mq = MQDecoder(bytes(data))
     decode = mq.decode
@@ -264,24 +270,81 @@ def decode_block(data: bytes, nbps: int, npasses: int, band: str,
 
     hv = np.array(habs, dtype=np.int64).reshape(h, w)
     if hv.size and int(hv.max()) >= (1 << 31):
-        raise DecodeError("decoded magnitude overflows int32")
+        raise _error(_OVERFLOW, nbps, npasses)
     hv = hv.astype(np.int32)
     hv[np.array(neg, dtype=bool).reshape(h, w)] *= -1
     return hv, n_dec
 
 
+# Per-block statuses of the native decoder (DecodeStatus in t1.cpp).
+_TOO_MANY_PLANES, _TOO_MANY_PASSES, _OVERFLOW = 1, 2, 3
+
+
+def _error(status: int, nbps: int, npasses: int) -> DecodeError:
+    """The DecodeError for a pass/plane count no conforming encoder can
+    emit (the packet header is attacker-controlled input)."""
+    if status == _TOO_MANY_PLANES:
+        return DecodeError(f"{nbps} bit-planes exceeds the 30-plane cap")
+    if status == _TOO_MANY_PASSES:
+        return DecodeError(
+            f"{npasses} passes exceeds the {max_passes(nbps)} possible "
+            f"for {nbps} bit-planes")
+    return DecodeError("decoded magnitude overflows int32")
+
+
 def decode_blocks(specs: list) -> tuple:
     """Batch entry: specs [(data, nbps, npasses, band, h, w)] ->
-    ([hvals arrays], total decisions). Blocks are independent (the same
-    property the encode side's thread pool exploits); kept sequential
-    here — the pure-Python MQ loop is GIL-bound either way."""
-    out = []
-    total = 0
+    ([hvals arrays], total decisions, whether the native decoder ran).
+
+    With the native library, one call decodes every block (one tile's,
+    from the decoder) with the GIL released; the installed deadline
+    check runs once before it. Without it, the Python loop decodes the
+    blocks in turn and checks the deadline between them."""
+    lib = native.load()
     check = getattr(_services, "check", None)
-    for data, nbps, npasses, band, h, w in specs:
-        if check is not None:
-            check()
-        hv, n = decode_block(data, nbps, npasses, band, h, w)
-        out.append(hv)
-        total += n
-    return out, total
+    if lib is None:
+        out = []
+        total = 0
+        for data, nbps, npasses, band, h, w in specs:
+            if check is not None:
+                check()
+            hv, n = decode_block(data, nbps, npasses, band, h, w)
+            out.append(hv)
+            total += n
+        return out, total, False
+
+    if check is not None:
+        check()
+    n = len(specs)
+    if not n:
+        return [], 0, True
+    datas = [s[0] for s in specs]
+    offsets = np.zeros(n + 1, dtype=np.int64)
+    np.cumsum([len(d) for d in datas], out=offsets[1:])
+    data = np.frombuffer(b"".join(datas), dtype=np.uint8)
+    # Rows: nbps, npasses, band class, h, w.
+    meta = np.array([(nbps, npasses, BAND_CLS[band], h, w)
+                     for _, nbps, npasses, band, h, w in specs],
+                    dtype=np.int32).T.copy()
+    if meta[3:].min() < 0:        # the native decoder writes h*w samples
+        raise DecodeError("negative code-block extent")
+    sizes = meta[3].astype(np.int64) * meta[4]
+    out_offsets = np.zeros(n + 1, dtype=np.int64)
+    np.cumsum(sizes, out=out_offsets[1:])
+    out = np.empty(int(out_offsets[-1]), dtype=np.int32)
+    decisions = np.empty(n, dtype=np.int64)
+    status = np.empty(n, dtype=np.int32)
+    threads = min(t1_batch.default_threads(), n)
+    t1_batch._note_call("t1_decode_blocks", n, threads)
+    lib.t1_decode_blocks(
+        n, data.ctypes.data, offsets.ctypes.data, meta[0].ctypes.data,
+        meta[1].ctypes.data, meta[2].ctypes.data, meta[3].ctypes.data,
+        meta[4].ctypes.data, out.ctypes.data, out_offsets.ctypes.data,
+        decisions.ctypes.data, status.ctypes.data, threads)
+    bad = np.flatnonzero(status)
+    if bad.size:
+        i = int(bad[0])
+        raise _error(int(status[i]), int(meta[0, i]), int(meta[1, i]))
+    hvs = [out[o:o + int(k)].reshape(int(h), int(w))
+           for o, k, h, w in zip(out_offsets[:-1], sizes, meta[3], meta[4])]
+    return hvs, int(decisions.sum()), True

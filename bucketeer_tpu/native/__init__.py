@@ -10,6 +10,11 @@ Kakadu-to-OpenJPEG degradation
 (reference: converters/ConverterFactory.java:37-47). Every block that
 takes that path is counted in ``codec.t1_batch.python_coded_blocks``.
 
+The read path decodes through the same library: ``t1_decode_blocks``
+decodes one tile's code-blocks per call (``codec/decode/t1_dec.py``);
+blocks that fall back to the Python decoder count in the
+``decode.python_blocks`` metric.
+
 Set ``BUCKETEER_NO_NATIVE=1`` to force the Python path.
 """
 from __future__ import annotations
@@ -25,7 +30,7 @@ LOG = logging.getLogger(__name__)
 
 _DIR = Path(__file__).resolve().parent
 _LIB_PATH = _DIR / "libbucketeer_t1.so"
-_ABI_VERSION = 4     # must match t1_abi_version() in t1.cpp
+_ABI_VERSION = 5     # must match t1_abi_version() in t1.cpp
 _lib = None
 _tried = False
 # Held across the first load and its build: the host Tier-1 pool calls
@@ -148,6 +153,9 @@ def _load_locked():
         ctypes.c_int, ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
         ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
         ctypes.c_void_p, ctypes.c_int]
+    lib.t1_decode_blocks.restype = None
+    lib.t1_decode_blocks.argtypes = [ctypes.c_int] + \
+        [ctypes.c_void_p] * 11 + [ctypes.c_int]
     lib.t1_block_sizes.restype = None
     lib.t1_block_sizes.argtypes = [ctypes.c_void_p] + [ctypes.c_void_p] * 3
     lib.t1_block_get.restype = None

@@ -12,8 +12,14 @@
 // reference's Lambda fan-out, sized like its uploader pool — cores-1,
 // reference: verticles/MainVerticle.java:64-77).
 //
+// The read path's decoder lives here too: t1_decode_blocks is the MQ
+// decoder and context modelling of codec/{mq.py,decode/t1_dec.py}, one
+// tile's blocks per call on the same pool, bit-exact with the Python
+// decoder (enforced by tests/test_native_t1_decode.py).
+//
 // Build: make -C bucketeer_tpu/native  (g++ -O3, no external deps).
 
+#include <algorithm>
 #include <atomic>
 #include <cstdint>
 #include <cstring>
@@ -133,6 +139,99 @@ struct MQEnc {
         byteout();
         if (buf.size() > 1 && buf.back() == 0xFF) buf.pop_back();
         // buf[0] stays the dummy byte; callers read buf[1..).
+    }
+};
+
+// ---- MQ decoder (T.800 Annex C.3; mirrors codec/mq.py MQDecoder) ----
+//
+// The segment is attacker-controlled (its length comes from a packet
+// header): bytes past its end read as 0xFF, exactly as mq.py's _byte
+// does, so a truncated or garbage stream decodes to the same decisions
+// as the Python decoder and never reads out of bounds. C is kept to 32
+// bits; every value the decoder observes depends on those bits alone.
+
+struct MQDec {
+    const uint8_t* data;
+    int64_t len;
+    int64_t bp = 0;
+    uint32_t a = 0x8000, c = 0;
+    int ct = 0;
+    uint8_t idx[N_CTX];
+    uint8_t mps[N_CTX];
+
+    MQDec(const uint8_t* d, int64_t n) : data(d), len(n) {
+        std::memset(idx, 0, sizeof(idx));
+        std::memset(mps, 0, sizeof(mps));
+        idx[0] = 4;
+        idx[CTX_RL] = 3;
+        idx[CTX_UNIFORM] = 46;
+        c = byte(0) << 16;
+        bytein();
+        c <<= 7;
+        ct -= 7;
+    }
+
+    uint32_t byte(int64_t i) const { return i < len ? data[i] : 0xFFu; }
+
+    void bytein() {
+        if (byte(bp) == 0xFF) {
+            if (byte(bp + 1) > 0x8F) {
+                c += 0xFF00;
+                ct = 8;
+            } else {
+                bp++;
+                c += byte(bp) << 9;
+                ct = 7;
+            }
+        } else {
+            bp++;
+            c += byte(bp) << 8;
+            ct = 8;
+        }
+    }
+
+    void renorm() {
+        do {
+            if (ct == 0) bytein();
+            a = (a << 1) & 0xFFFF;
+            c <<= 1;
+            ct--;
+        } while (!(a & 0x8000));
+    }
+
+    int decode(int ctx) {
+        const QeRow& row = QE[idx[ctx]];
+        const uint32_t qe = row.qe;
+        int d;
+        a -= qe;
+        if ((c >> 16) < qe) {            // LPS exchange
+            if (a < qe) {
+                d = mps[ctx];
+                idx[ctx] = row.nmps;
+            } else {
+                d = 1 - mps[ctx];
+                if (row.sw) mps[ctx] ^= 1;
+                idx[ctx] = row.nlps;
+            }
+            a = qe;
+            renorm();
+        } else {
+            c -= qe << 16;
+            if (!(a & 0x8000)) {         // MPS exchange
+                if (a < qe) {
+                    d = 1 - mps[ctx];
+                    if (row.sw) mps[ctx] ^= 1;
+                    idx[ctx] = row.nlps;
+                } else {
+                    d = mps[ctx];
+                    idx[ctx] = row.nmps;
+                }
+                renorm();
+            } else {
+                d = mps[ctx];
+            }
+        }
+        return d;
     }
 };
 
@@ -401,6 +500,174 @@ static void encode_block(const uint32_t* mags, const uint8_t* negs,
         if (pr.cum_len > total) pr.cum_len = total;
 }
 
+// ---- Block decoder (T.800 Annex D; mirrors codec/decode/t1_dec.py) ----
+
+// Per-block status of t1_decode_blocks; t1_dec.py raises the DecodeError
+// decode_block raises for the same input.
+enum DecodeStatus {
+    DEC_OK = 0,
+    DEC_TOO_MANY_PLANES = 1,   // nbps > 30
+    DEC_TOO_MANY_PASSES = 2,   // npasses > 3 * nbps - 2
+    DEC_OVERFLOW = 3,          // a half-magnitude >= 2^31
+};
+
+// One code-block's pass stream -> (h, w) signed half-magnitudes
+// |hval| = (2m + 1) << p, row-major in out. Stops after npasses passes.
+static int decode_block(const uint8_t* data, int64_t len, int nbps,
+                        int npasses, int bandcls, int h, int w,
+                        int32_t* out, int64_t& n_dec) {
+    n_dec = 0;
+    const int64_t size = (int64_t)h * w;
+    std::fill(out, out + size, 0);
+    if (nbps <= 0 || npasses <= 0) return DEC_OK;
+    if (nbps > 30) return DEC_TOO_MANY_PLANES;
+    if (npasses > 3 * nbps - 2) return DEC_TOO_MANY_PASSES;
+
+    // Neighbour-significance counters, bumped only when a sample becomes
+    // significant (bounded by 2, 2 and 4, so they index the ZC tables).
+    std::vector<uint8_t> sigma(size, 0), pi(size, 0), refined(size, 0),
+        neg(size, 0), nbh(size, 0), nbv(size, 0), nbd(size, 0);
+    std::vector<int64_t> habs(size, 0);
+
+    const bool swap_hv = bandcls == 2;
+    const auto& zc = bandcls == 1 ? T.zc_hh : T.zc_ll_lh;
+    auto zc_ctx = [&](int64_t i) -> int {
+        return swap_hv ? zc[nbv[i]][nbh[i]][nbd[i]]
+                       : zc[nbh[i]][nbv[i]][nbd[i]];
+    };
+
+    MQDec mq(data, len);
+
+    auto set_sig = [&](int64_t i, int y, int x) {
+        sigma[i] = 1;
+        if (x > 0) {
+            nbh[i - 1]++;
+            if (y > 0) nbd[i - 1 - w]++;
+            if (y < h - 1) nbd[i - 1 + w]++;
+        }
+        if (x < w - 1) {
+            nbh[i + 1]++;
+            if (y > 0) nbd[i + 1 - w]++;
+            if (y < h - 1) nbd[i + 1 + w]++;
+        }
+        if (y > 0) nbv[i - w]++;
+        if (y < h - 1) nbv[i + w]++;
+    };
+
+    auto decode_sign = [&](int64_t i, int y, int x) -> uint8_t {
+        int hc = 0, vc = 0;
+        if (x > 0 && sigma[i - 1]) hc += neg[i - 1] ? -1 : 1;
+        if (x < w - 1 && sigma[i + 1]) hc += neg[i + 1] ? -1 : 1;
+        if (y > 0 && sigma[i - w]) vc += neg[i - w] ? -1 : 1;
+        if (y < h - 1 && sigma[i + w]) vc += neg[i + w] ? -1 : 1;
+        hc = hc > 1 ? 1 : (hc < -1 ? -1 : hc);
+        vc = vc > 1 ? 1 : (vc < -1 ? -1 : vc);
+        return (uint8_t)(mq.decode(T.sc_ctx[hc + 1][vc + 1]) ^
+                         T.sc_xor[hc + 1][vc + 1]);
+    };
+
+    int left = npasses;
+    bool first_plane = true;
+    for (int p = nbps - 1; p >= 0; p--) {
+        const int64_t bit3 = (int64_t)3 << p;
+        const int64_t bit1 = (int64_t)1 << p;
+
+        if (!first_plane) {
+            // Pass 1: significance propagation.
+            for (int y0 = 0; y0 < h; y0 += 4) {
+                const int ymax = y0 + 4 < h ? y0 + 4 : h;
+                for (int x = 0; x < w; x++) {
+                    int64_t i = (int64_t)y0 * w + x;
+                    for (int y = y0; y < ymax; y++, i += w) {
+                        if (sigma[i] || !(nbh[i] | nbv[i] | nbd[i]))
+                            continue;
+                        n_dec++;
+                        pi[i] = 1;
+                        if (mq.decode(zc_ctx(i))) {
+                            n_dec++;
+                            neg[i] = decode_sign(i, y, x);
+                            set_sig(i, y, x);
+                            habs[i] = bit3;
+                        }
+                    }
+                }
+            }
+            if (--left == 0) break;
+
+            // Pass 2: magnitude refinement.
+            for (int y0 = 0; y0 < h; y0 += 4) {
+                const int ymax = y0 + 4 < h ? y0 + 4 : h;
+                for (int x = 0; x < w; x++) {
+                    int64_t i = (int64_t)y0 * w + x;
+                    for (int y = y0; y < ymax; y++, i += w) {
+                        if (!sigma[i] || pi[i]) continue;
+                        int ctx;
+                        if (refined[i]) ctx = 16;
+                        else ctx = (nbh[i] | nbv[i] | nbd[i]) ? 15 : 14;
+                        n_dec++;
+                        if (mq.decode(ctx)) habs[i] += bit1;
+                        else habs[i] -= bit1;
+                        refined[i] = 1;
+                    }
+                }
+            }
+            if (--left == 0) break;
+        }
+
+        // Pass 3: cleanup, with the run-length shortcut.
+        for (int y0 = 0; y0 < h; y0 += 4) {
+            const int ymax = y0 + 4 < h ? y0 + 4 : h;
+            for (int x = 0; x < w; x++) {
+                const int64_t i0 = (int64_t)y0 * w + x;
+                int y = y0;
+                if (y0 + 3 < h) {
+                    bool rl = true;
+                    int64_t i = i0;
+                    for (int k = 0; k < 4; k++, i += w)
+                        if (sigma[i] | pi[i] | nbh[i] | nbv[i] | nbd[i]) {
+                            rl = false;
+                            break;
+                        }
+                    if (rl) {
+                        n_dec++;
+                        if (!mq.decode(CTX_RL)) continue;
+                        n_dec += 2;
+                        int k = mq.decode(CTX_UNIFORM) << 1;
+                        k |= mq.decode(CTX_UNIFORM);
+                        const int yk = y0 + k;
+                        const int64_t ik = i0 + (int64_t)k * w;
+                        n_dec++;
+                        neg[ik] = decode_sign(ik, yk, x);
+                        set_sig(ik, yk, x);
+                        habs[ik] = bit3;
+                        y = yk + 1;
+                    }
+                }
+                int64_t i = i0 + (int64_t)(y - y0) * w;
+                for (int yy = y; yy < ymax; yy++, i += w) {
+                    if (sigma[i] || pi[i]) continue;
+                    n_dec++;
+                    if (mq.decode(zc_ctx(i))) {
+                        n_dec++;
+                        neg[i] = decode_sign(i, yy, x);
+                        set_sig(i, yy, x);
+                        habs[i] = bit3;
+                    }
+                }
+            }
+        }
+        if (--left == 0) break;
+        std::fill(pi.begin(), pi.end(), 0);
+        first_plane = false;
+    }
+
+    for (int64_t i = 0; i < size; i++) {
+        if (habs[i] >= ((int64_t)1 << 31)) return DEC_OVERFLOW;
+        out[i] = neg[i] ? -(int32_t)habs[i] : (int32_t)habs[i];
+    }
+    return DEC_OK;
+}
+
 struct T1Result {
     std::vector<BlockOut> blocks;
 };
@@ -433,7 +700,7 @@ extern "C" {
 // refuses a library whose version doesn't match, so a stale prebuilt
 // .so (deployment images may prune t1.cpp) fails loudly instead of
 // misreading the new argument layout.
-int32_t t1_abi_version() { return 4; }
+int32_t t1_abi_version() { return 5; }
 
 T1Result* t1_encode_blocks(int n_blocks,
                            const uint32_t* mags, const uint8_t* negs,
@@ -543,6 +810,27 @@ T1Result* t1_encode_cxd(int n_blocks, const uint8_t* payload,
             if (pr.cum_len > total) pr.cum_len = total;
     });
     return res;
+}
+
+// Decode entry (the read path, codec/decode/t1_dec.py). Block i's
+// segment is data[offsets[i], offsets[i+1]); its hs[i] x ws[i] signed
+// half-magnitudes are written row-major at out + out_offsets[i], its MQ
+// decision count to decisions[i] and a DecodeStatus to status[i].
+// Results go straight into caller-owned arrays: no handle to free.
+void t1_decode_blocks(int n_blocks, const uint8_t* data,
+                      const int64_t* offsets,
+                      const int32_t* nbps, const int32_t* npasses,
+                      const int32_t* bandcls,
+                      const int32_t* hs, const int32_t* ws,
+                      int32_t* out, const int64_t* out_offsets,
+                      int64_t* decisions, int32_t* status,
+                      int n_threads) {
+    run_pool(n_blocks, n_threads, [&](int i) {
+        status[i] = decode_block(data + offsets[i],
+                                 offsets[i + 1] - offsets[i], nbps[i],
+                                 npasses[i], bandcls[i], hs[i], ws[i],
+                                 out + out_offsets[i], decisions[i]);
+    });
 }
 
 void t1_block_sizes(T1Result* r, int32_t* nbps, int32_t* npasses,
