@@ -914,12 +914,16 @@ def encode_array(img: np.ndarray, bitdepth: int = 8,
         _tm_add("device", time.perf_counter() - t0)
 
     def host_code(chunk: _Chunk, floors: np.ndarray, payload: np.ndarray,
-                  offsets: np.ndarray, pixels: int) -> list:
+                  offsets: np.ndarray, pixels: int,
+                  submitted: float) -> list:
         """Runs on the bounded worker; native Tier-1 releases the GIL,
         so this overlaps the caller's device dispatch/waits. Submitted
         through obs.bind so the pool thread re-enters the request's
         trace context (host Tier-1 items show in the span tree). Each
-        chunk records encode.host_code as it finishes."""
+        chunk records its wait for a worker (encode.t1_wait, from
+        ``submitted``) as it starts and encode.host_code as it
+        finishes."""
+        obs.waited(_metrics_sink, "encode.t1_wait", submitted, pixels)
         with obs.stage(_metrics_sink, "encode.host_code", pixels,
                        span_name="encode.host_t1",
                        blocks=len(chunk.dests)) as st:
@@ -933,9 +937,11 @@ def encode_array(img: np.ndarray, bitdepth: int = 8,
         _tm_add("host", st.seconds)
         return blocks
 
-    def host_replay(chunk: _Chunk, streams, pixels: int) -> list:
+    def host_replay(chunk: _Chunk, streams, pixels: int,
+                    submitted: float) -> list:
         """The CX/D-mode host half: pure MQ replay of the device's
         symbol streams — no context modeling left on the host."""
+        obs.waited(_metrics_sink, "encode.t1_wait", submitted, pixels)
         with obs.stage(_metrics_sink, "encode.host_code", pixels,
                        span_name="encode.mq_replay",
                        blocks=len(chunk.dests)) as st:
@@ -1022,12 +1028,13 @@ def encode_array(img: np.ndarray, bitdepth: int = 8,
             live[0].result()
         # obs.bind: the shared pool's threads don't inherit contextvars;
         # rebind the request's trace context around the host-coding item.
+        submitted = time.perf_counter()
         if use_cxd:
             futs.append(pool.submit(obs.bind(host_replay), chunk,
-                                    streams, pixels))
+                                    streams, pixels, submitted))
         else:
             futs.append(pool.submit(obs.bind(host_code), chunk, floors,
-                                    payload, offsets, pixels))
+                                    payload, offsets, pixels, submitted))
 
     def chunk_floors(margin: float) -> list:
         if target is None:

@@ -29,6 +29,7 @@ on TPU and on the CPU backend (no-TPU dev mode / tests).
 from __future__ import annotations
 
 import threading
+from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
 from functools import lru_cache, partial
 
@@ -299,7 +300,7 @@ class PendingFrontend:
           dtypes={"tiles": "number"})
 def dispatch_frontend(plan: TilePlan, tiles: np.ndarray,
                       mode: str = "rows",
-                      device=None) -> PendingFrontend:
+                      device=None, pool=()) -> PendingFrontend:
     """Queue transform + blockify + stats for a (B, h, w[, C]) tile
     batch on the device and return without waiting for the result.
     ``mode="cxd"`` keeps the raw blockified coefficients on device for
@@ -311,7 +312,10 @@ def dispatch_frontend(plan: TilePlan, tiles: np.ndarray,
     pipelines apart. ``device`` (a ``jax.Device``) stages the batch
     with a *committed* ``jax.device_put`` so the program — and every
     downstream stage consuming its output, even from another thread —
-    runs on that core; None keeps default placement."""
+    runs on that core; None keeps default placement. ``pool``: the
+    devices of the scheduler's pool that ``device`` belongs to; on a
+    pool of more than one, the first launch of a shape readies its
+    programs on all of them (:func:`_ready_on_pool`)."""
     if tiles.ndim == 3:
         tiles = tiles[..., None]
     # Dtype audit at the host->device boundary: the device program's
@@ -339,9 +343,57 @@ def dispatch_frontend(plan: TilePlan, tiles: np.ndarray,
     staged = (jax.device_put(tiles, device) if device is not None
               else jnp.asarray(tiles))
     out, stats = _compiled_frontend(plan, layout.P, prog_mode)(staged)
+    if len(pool) > 1:
+        _ready_on_pool(pool, device, plan, layout.P, prog_mode, staged,
+                       out if prog_mode == "rows" else None)
     if prog_mode == "rows":
         return PendingFrontend(layout, b, out, stats)
     return PendingFrontend(layout, b, None, stats, blocks=out)
+
+
+# (program shape, device) pairs already made ready by _ready_on_pool:
+# process-wide, as JAX's cache of compiled programs is.
+_POOL_READY: set = set()
+_POOL_READY_LOCK = threading.Lock()
+
+
+def _ready_on_pool(pool, device, plan: TilePlan, P: int, mode: str,
+                   staged, rows) -> None:
+    """Compile, or load from the persistent cache, the programs of a
+    launch's shape on every device of the pool, once per shape and
+    device, before the launch returns: the front-end on the devices
+    other than ``device`` (whose call just did it), and, given the
+    launch's ``rows``, the payload gather on all of them. A compiled
+    program is keyed by its device assignment, so without this a shape
+    warmed on one device compiles again the first time another runs
+    it, which the retrace counters (they count traces) do not show.
+    The lowerings match the calls exactly: committed inputs carry the
+    device's sharding, the gather's uncommitted index none."""
+    key = (plan, P, mode, staged.shape, staged.dtype)
+    with _POOL_READY_LOCK:
+        todo = [d for d in pool if (key, d) not in _POOL_READY]
+        jobs = []
+        for d in todo:
+            on_d = jax.sharding.SingleDeviceSharding(d)
+            if d != device:
+                jobs.append((_compiled_frontend(plan, P, mode),
+                             (jax.ShapeDtypeStruct(staged.shape,
+                                                   staged.dtype,
+                                                   sharding=on_d),)))
+            if rows is not None:
+                jobs.append((_compiled_gather(GATHER_CHUNK),
+                             (jax.ShapeDtypeStruct(rows.shape, rows.dtype,
+                                                   sharding=on_d),
+                              jax.ShapeDtypeStruct(
+                                  (GATHER_CHUNK,),
+                                  jax.dtypes.canonicalize_dtype(
+                                      np.int64)))))
+        if jobs:
+            # One thread per program: XLA compiles outside the GIL.
+            with ThreadPoolExecutor(len(jobs)) as ex:
+                list(ex.map(lambda job: job[0].lower(*job[1]).compile(),
+                            jobs))
+        _POOL_READY.update((key, d) for d in todo)
 
 
 @contract(shapes={"tiles": [("B", "h", "w"), ("B", "h", "w", "C")]},

@@ -21,7 +21,11 @@ access and host Tier-1 capacity instead:
   batch with ``jax.device_put(..., device)`` so the compiled program
   runs on the worker's own core — committed inputs keep every
   downstream device stage (gather, fused Tier-1) on that core instead
-  of thrashing back to device 0.
+  of thrashing back to device 0. On a pool of several devices a
+  front-end shape's first launch compiles (or loads) its front-end and
+  gather programs on every pool device before it returns
+  (codec/frontend._ready_on_pool): a warm-up that reaches a shape
+  reaches it everywhere, and a worker spawned later compiles nothing.
 - **Continuous batching** — compatible chunks from *different* requests
   (same tile plan, mode, dtype) are concatenated into one launch,
   padded to the existing power-of-two batch buckets (pipeline._bucket)
@@ -78,7 +82,9 @@ launch), counters ``{encode,decode,tensor}.admission_rejects``,
 ``....device_launches.d<N>`` split (real worker device ids — the PR 16
 placeholder that booked everything on d0 is gone),
 ``{decode,tensor}.device_assigned.d<N>`` for request-thread placement,
-``encode.batched_tiles``, ``tensor.batched_blocks``,
+``encode.batched_tiles`` plus its per-device ``encode.batched_tiles.d<N>``
+(every launch reports every pool device, an unused one as 0),
+``tensor.batched_blocks``,
 ``{encode,decode}.deadline_expired``. Merged-launch spans carry the
 worker's ``device_id``. A ``sched`` reporter on the sink adds the
 per-device occupancy gauge (``sched.device_occupancy.d<N>``: busy
@@ -1246,8 +1252,13 @@ class EncodeScheduler:
         if launch is None:
             from ..codec import frontend
             if dev is not None:
+                # A pool of several devices: a shape's first launch
+                # readies its programs on every one of them. A pool of
+                # one launches as it always did.
+                pool_kw = ({"pool": tuple(self._devices)}
+                           if len(self._devices) > 1 else {})
                 launch = functools.partial(frontend.dispatch_frontend,
-                                           device=dev)
+                                           device=dev, **pool_kw)
             else:
                 launch = frontend.dispatch_frontend
 
@@ -1290,6 +1301,10 @@ class EncodeScheduler:
                 self._sink.count("encode.device_launches")
                 self._sink.count(f"encode.device_launches.d{widx}")
                 self._sink.count("encode.batched_tiles", n_tiles)
+                # Every pool device reports its tiles, an unused one 0.
+                for i in range(len(self._devices)):
+                    self._sink.count(f"encode.batched_tiles.d{i}",
+                                     n_tiles if i == widx else 0)
                 self._sink.observe("encode.batch_occupancy", len(group))
             for j in group:
                 # A fatally-interrupted launch (BaseException in

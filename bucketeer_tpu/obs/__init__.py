@@ -18,7 +18,8 @@ surface:
   feeding breach counters and flight dumps.
 - :mod:`.logctx` — every log record gains ``request_id``.
 - :func:`stage` — a span that also feeds its ``/metrics`` stage as it
-  closes: the one seam for a timed layer boundary.
+  closes: the one seam for a timed layer boundary; :func:`waited` the
+  same for a wait that began on another thread.
 - :mod:`.cost` — graftcost-modeled stage costs for the scheduler's
   pipeline mapper.
 
@@ -31,7 +32,7 @@ from .slo import SloWatchdog  # noqa: F401
 from .trace import (LAYER_SPANS, Recorder, bind,  # noqa: F401
                     current_context, current_request_id, get_recorder,
                     install, installed, maybe_install, request_context,
-                    span, stage, use_context)
+                    span, stage, use_context, waited)
 
 
 def chrome_trace(request_id):

@@ -418,6 +418,26 @@ def stage(sink, name: str, pixels: int = 0, items: int = 0, *,
                   attrs)
 
 
+def waited(sink, name: str, since: float, pixels: int = 0) -> None:
+    """Close a wait that began at ``since`` (``time.perf_counter()``,
+    taken where the work was handed over, often on another thread) and
+    ends now: one completed span ``name`` under the current context,
+    and one record into ``sink``'s ``/metrics`` stage ``name``. Never a
+    profiler annotation: a wait names no layer's work."""
+    seconds = max(0.0, time.perf_counter() - since)
+    if sink is not None:
+        sink.record(name, seconds, pixels)
+    rec = _REC
+    if rec is None:
+        return
+    trace_id, parent_id = _CTX.get() or (None, None)
+    s = Span(trace_id, next(rec._ids), parent_id, name,
+             seam.monotonic() - seconds, threading.current_thread().name,
+             {"pixels": pixels})
+    s.dur = seconds
+    rec._finish(s)
+
+
 def current_context():
     """The (trace_id, span_id) pair of the active span, or None."""
     return _CTX.get()

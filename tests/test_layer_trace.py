@@ -87,12 +87,10 @@ def test_layer_spans_enter_the_profiler_trace(rng, tmp_path, monkeypatch,
     assert not {n for n in names if n.startswith("http.")}
 
 
-def test_host_code_records_each_chunk_and_counts_pixels_once(
-        rng, monkeypatch, sink):
-    """encode.host_code is recorded per chunk as it finishes; a floor
-    rerun adds records and time but no pixels, so the stage's pixels
-    are the image's once. encode.fetch_payload counts the same way, and
-    the native call is its own stage inside each chunk."""
+def _encode_with_a_floor_rerun(rng, monkeypatch) -> tuple:
+    """A 256^2 lossy encode in chunks of two 64^2 tiles whose first
+    floors code nothing, so every chunk is coded twice. Returns (h, w,
+    chunks)."""
     monkeypatch.setenv("BUCKETEER_OVERLAP_TILES", "2")
     real = rate_mod.estimate_floors
     calls = []
@@ -113,8 +111,17 @@ def test_host_code_records_each_chunk_and_counts_pixels_once(
         lossless=False, levels=3, tile_size=64, rate=2.0, n_layers=3,
         base_delta=0.5))
     assert decode(data).shape == img.shape
+    return h, w, (h // 64) * (w // 64) // 2
+
+
+def test_host_code_records_each_chunk_and_counts_pixels_once(
+        rng, monkeypatch, sink):
+    """encode.host_code is recorded per chunk as it finishes; a floor
+    rerun adds records and time but no pixels, so the stage's pixels
+    are the image's once. encode.fetch_payload counts the same way, and
+    the native call is its own stage inside each chunk."""
+    h, w, chunks = _encode_with_a_floor_rerun(rng, monkeypatch)
     assert sink.counters["encode.floor_reruns"] == 1
-    chunks = (h // 64) * (w // 64) // 2
     for name in ("encode.host_code", "encode.fetch_payload", "t1.native"):
         assert sink.stages[name].count == 2 * chunks, name
     for name in ("encode.host_code", "encode.fetch_payload",
@@ -122,6 +129,31 @@ def test_host_code_records_each_chunk_and_counts_pixels_once(
         assert sink.stages[name].pixels == h * w, name
     assert sink.stages["t1.native"].total_s <= \
         sink.stages["encode.host_code"].total_s
+
+
+def test_t1_wait_records_each_chunk_and_counts_pixels_once(
+        rng, monkeypatch, sink):
+    """encode.t1_wait, a chunk's wait for a worker of the Tier-1 pool,
+    is recorded once per Tier-1 chunk as its host Tier-1 starts, with
+    the chunk's pixels on its first attempt only; its span belongs to
+    the request and stays out of the profiler's layer spans."""
+    prev = obs.get_recorder()
+    rec = Recorder()
+    obs.install(rec)
+    try:
+        with obs.request_context("wait-1"):
+            h, w, chunks = _encode_with_a_floor_rerun(rng, monkeypatch)
+    finally:
+        obs.install(prev)
+    waits = sink.stages["encode.t1_wait"]
+    assert waits.count == sink.stages["encode.host_code"].count == 2 * chunks
+    assert waits.pixels == h * w
+    spans = [s for s in rec.snapshot() if s["name"] == "encode.t1_wait"]
+    assert len(spans) == 2 * chunks
+    assert {s["trace_id"] for s in spans} == {"wait-1"}
+    assert sorted(s["attrs"]["pixels"] for s in spans) == \
+        [0] * chunks + [2 * 64 * 64] * chunks
+    assert "encode.t1_wait" not in obs.LAYER_SPANS
 
 
 def test_decode_records_mq_once_per_tile(rng, sink):
@@ -157,6 +189,7 @@ FULL = {
     "convert.tiff_read": {"count": 2, "sum_s": 0.16, "mpixels": 8.0},
     "s3.put": {"count": 5, "sum_s": 0.5},
     "decode.device_inverse": {"count": 12, "sum_s": 1.2},
+    "encode.t1_wait": {"count": 4, "sum_s": 0.2, "mpixels": 8.0},
 }
 
 
@@ -168,6 +201,7 @@ FULL = {
     ("tiff_read_ms_per_mpix", 20.0, ("convert.tiff_read",)),
     ("s3_put_ms", 100.0, ("s3.put",)),
     ("inverse_host_ms_per_read", 150.0, ("decode.device_inverse",)),
+    ("t1_wait_ms_per_mpix", 25.0, ("encode.t1_wait",)),
 ])
 def test_layer_metric_reader(monkeypatch, name, expected, needs):
     """Each reader's value from a hand-made window, and None (the
