@@ -273,13 +273,13 @@ def _tier1_split_one(encoder, Metrics, img, p, mode,
         # Record the host Tier-1 inputs so the caller can re-time the
         # host calls in isolation after the encode. In device-MQ mode
         # the host's whole Tier-1 share is the block assembly.
-        def cap_packed(*args):
+        def cap_packed(*args, **kwargs):
             capture.append(args)
-            return orig[0](*args)
+            return orig[0](*args, **kwargs)
 
-        def cap_cxd(streams):
+        def cap_cxd(streams, **kwargs):
             capture.append((streams,))
-            return orig[1](streams)
+            return orig[1](streams, **kwargs)
 
         def cap_mq(*args):
             capture.append(args)

@@ -913,6 +913,13 @@ def encode_array(img: np.ndarray, bitdepth: int = 8,
         chunk.pending = None
         _tm_add("device", time.perf_counter() - t0)
 
+    def exact_of(chunk: _Chunk):
+        """The device's exact per-plane distortion sums that replace
+        host Tier-1's estimates (lossy only: lossless ships every pass
+        and PCRD never cuts)."""
+        return (None if params.lossless
+                else (chunk.fres.sigd, chunk.fres.refd))
+
     def host_code(chunk: _Chunk, floors: np.ndarray, payload: np.ndarray,
                   offsets: np.ndarray, pixels: int,
                   submitted: float) -> list:
@@ -931,9 +938,8 @@ def encode_array(img: np.ndarray, bitdepth: int = 8,
                                             chunk.fres.nbps,
                                             floors, chunk.hs, chunk.ws,
                                             chunk.bandnames,
-                                            sink=_metrics_sink)
-            if not params.lossless:
-                _correct_distortions(blocks, chunk.fres)
+                                            sink=_metrics_sink,
+                                            exact=exact_of(chunk))
         _tm_add("host", st.seconds)
         return blocks
 
@@ -945,9 +951,7 @@ def encode_array(img: np.ndarray, bitdepth: int = 8,
         with obs.stage(_metrics_sink, "encode.host_code", pixels,
                        span_name="encode.mq_replay",
                        blocks=len(chunk.dests)) as st:
-            blocks = t1_batch.encode_cxd(streams)
-            if not params.lossless:
-                _correct_distortions(blocks, chunk.fres)
+            blocks = t1_batch.encode_cxd(streams, exact=exact_of(chunk))
         _tm_add("host", st.seconds)
         _tm_add("mq", st.seconds)
         return blocks
@@ -989,7 +993,7 @@ def encode_array(img: np.ndarray, bitdepth: int = 8,
             blocks = res.blocks
             th0 = time.perf_counter()
             if not params.lossless:
-                _correct_distortions(blocks, chunk.fres)
+                t1_batch.correct_distortions(blocks, exact_of(chunk))
             # The whole host share: assembly + distortion correction.
             host_s = res.host_s + time.perf_counter() - th0
             _tm_add("host", host_s)
@@ -1259,40 +1263,6 @@ def _finish_spanned(img: np.ndarray, params: EncodeParams,
             _metrics_sink.count("encode.t2_rebuilds")
         out = build(budget)
     return out
-
-
-def _correct_distortions(blocks: list, fres) -> None:
-    """Replace the host coder's fractionless per-pass distortion
-    estimates with the device front-end's exact per-plane sums.
-
-    The packed payload ships no fractional-magnitude bits (and, under a
-    bit-plane floor, no low integer bits), so native Tier-1's midpoint
-    estimates are biased; the device computed the exact per-plane
-    significance/refinement distortion totals from the full fixed-point
-    coefficients (frontend._frontend_body). Pass-level granularity is
-    recovered by scaling each pass in plane p by the exact/estimated
-    plane-total ratio for its kind (sig = SPP+CP, ref = MRP)."""
-    P = fres.layout.P
-    for bi, blk in enumerate(blocks):
-        if not blk.passes:
-            continue
-        est_sig = [0.0] * P
-        est_ref = [0.0] * P
-        for info in blk.passes:
-            if info.pass_type == 1:
-                est_ref[info.bitplane] += info.dist_reduction
-            else:
-                est_sig[info.bitplane] += info.dist_reduction
-        for info in blk.passes:
-            p = info.bitplane
-            est = est_ref[p] if info.pass_type == 1 else est_sig[p]
-            exact = (fres.refd[bi, p] if info.pass_type == 1
-                     else fres.sigd[bi, p])
-            if est > 0.0 and exact >= 0.0:
-                info.dist_reduction *= exact / est
-            # A zero estimate with nonzero exact distortion cannot be
-            # apportioned; keep the estimate (it is zero) — the hull
-            # treats the pass as free distortion-wise either way.
 
 
 def _qcd_values(plan: TilePlan) -> list:

@@ -59,33 +59,87 @@ def default_threads() -> int:
     return max(1, (os.cpu_count() or 2) - 1)
 
 
-def _collect(lib, handle, n: int) -> list:
-    """Pull a native T1Result handle into [t1.CodedBlock]."""
+def _correct(pdist: np.ndarray, ptype: np.ndarray, pplane: np.ndarray,
+             block: np.ndarray, exact) -> None:
+    """Replace the host coder's fractionless per-pass distortion
+    estimates with the device front-end's exact per-plane sums, in
+    place on the flat pass arrays (``block`` is each pass's block).
+
+    The packed payload ships no fractional-magnitude bits (and, under a
+    bit-plane floor, no low integer bits), so native Tier-1's midpoint
+    estimates are biased; the device computed the exact per-plane
+    significance/refinement distortion totals from the full fixed-point
+    coefficients (frontend._frontend_body), ``exact = (sigd, refd)``,
+    each (n_blocks, P). Pass-level granularity is recovered by scaling
+    each pass in plane p by the exact/estimated plane-total ratio for
+    its kind (sig = SPP+CP, ref = MRP). A zero estimate cannot be
+    apportioned and is kept (the hull treats the pass as free
+    distortion-wise either way)."""
+    sigd, refd = exact
+    ref = ptype == 1
+    key = (block * sigd.shape[1] + pplane) * 2 + ref
+    # bincount adds each key's weights in pass order: the estimate is
+    # the same float a pass-by-pass running sum gives.
+    est = np.bincount(key, weights=pdist)[key]
+    ex = np.where(ref, refd[block, pplane], sigd[block, pplane])
+    ok = (est > 0.0) & (ex >= 0.0)
+    # The ratio and the product in the exact sums' precision (float32
+    # from the device), as scalar numpy arithmetic on them would round.
+    ratio = ex[ok] / est[ok].astype(ex.dtype)
+    pdist[ok] = pdist[ok].astype(ratio.dtype) * ratio
+
+
+def correct_distortions(blocks: list, exact) -> None:
+    """:func:`_correct` on blocks already built: the device-MQ path's
+    (from ``cxd.run_device_mq``) and the pure-Python fallbacks'."""
+    passes = [p for b in blocks for p in b.passes]
+    if not passes:
+        return
+    k = len(passes)
+    pdist = np.fromiter((p.dist_reduction for p in passes), np.float64, k)
+    ptype = np.fromiter((p.pass_type for p in passes), np.int64, k)
+    pplane = np.fromiter((p.bitplane for p in passes), np.int64, k)
+    block = np.repeat(np.arange(len(blocks)),
+                      [len(b.passes) for b in blocks])
+    _correct(pdist, ptype, pplane, block, exact)
+    for p, d in zip(passes, pdist.tolist()):
+        p.dist_reduction = d
+
+
+def _collect(lib, handle, n: int, exact=None) -> list:
+    """Pull a native T1Result handle into [t1.CodedBlock]: one native
+    copy of every block's bytes and passes into flat arrays, the
+    distortion correction on those arrays when ``exact`` (the device's
+    per-plane ``(sigd, refd)``) is given, then the objects in bulk."""
     try:
         nbps = np.zeros(n, dtype=np.int32)
         npasses = np.zeros(n, dtype=np.int32)
         nbytes = np.zeros(n, dtype=np.int64)
         lib.t1_block_sizes(handle, nbps.ctypes.data, npasses.ctypes.data,
                            nbytes.ctypes.data)
-        out = []
-        for i in range(n):
-            np_i, nb_i = int(npasses[i]), int(nbytes[i])
-            data = np.empty(max(nb_i, 1), dtype=np.uint8)
-            ptype = np.zeros(max(np_i, 1), dtype=np.int32)
-            pplane = np.zeros(max(np_i, 1), dtype=np.int32)
-            plen = np.zeros(max(np_i, 1), dtype=np.int64)
-            pdist = np.zeros(max(np_i, 1), dtype=np.float64)
-            lib.t1_block_get(handle, i, data.ctypes.data, ptype.ctypes.data,
-                             pplane.ctypes.data, plen.ctypes.data,
-                             pdist.ctypes.data)
-            passes = [t1.PassInfo(int(ptype[k]), int(pplane[k]),
-                                  int(plen[k]), float(pdist[k]))
-                      for k in range(np_i)]
-            out.append(t1.CodedBlock(bytes(data[:nb_i].tobytes()),
-                                     int(nbps[i]), passes))
-        return out
+        boffs = np.concatenate(([0], np.cumsum(nbytes))).tolist()
+        poffs = np.concatenate(([0], np.cumsum(npasses))).tolist()
+        k = poffs[-1]
+        data = np.empty(max(boffs[-1], 1), dtype=np.uint8)
+        ptype = np.empty(max(k, 1), dtype=np.int32)
+        pplane = np.empty(max(k, 1), dtype=np.int32)
+        plen = np.empty(max(k, 1), dtype=np.int64)
+        pdist = np.empty(max(k, 1), dtype=np.float64)
+        lib.t1_result_copy(handle, data.ctypes.data, ptype.ctypes.data,
+                           pplane.ctypes.data, plen.ctypes.data,
+                           pdist.ctypes.data)
     finally:
         lib.t1_result_free(handle)
+    ptype, pplane, plen, pdist = ptype[:k], pplane[:k], plen[:k], pdist[:k]
+    if exact is not None:
+        _correct(pdist, ptype, pplane, np.repeat(np.arange(n), npasses),
+                 exact)
+    buf = data[:boffs[-1]].tobytes()
+    passes = list(map(t1.PassInfo, ptype.tolist(), pplane.tolist(),
+                      plen.tolist(), pdist.tolist()))
+    return [t1.CodedBlock(buf[boffs[i]:boffs[i + 1]], nbp,
+                          passes[poffs[i]:poffs[i + 1]])
+            for i, nbp in enumerate(nbps.tolist())]
 
 
 @contract(shapes={"payload": ("R", 512), "offsets": ("n1",),
@@ -97,16 +151,18 @@ def _collect(lib, handle, n: int) -> list:
 def encode_packed(payload: np.ndarray, offsets: np.ndarray,
                   nbps: np.ndarray, floors: np.ndarray,
                   hs: np.ndarray, ws: np.ndarray,
-                  bands: list, sink=None) -> list:
+                  bands: list, sink=None, exact=None) -> list:
     """Tier-1 over the device front-end's packed bitmap payload
     (codec/frontend.py): payload (R, 512) uint8 rows, offsets (n+1,)
     row offsets per block, per-block nbps/floors/dims and band names.
+    ``exact``: the device's per-plane ``(sigd, refd)`` distortion sums
+    (lossy), which replace the coder's estimates (:func:`_correct`).
     Returns [t1.CodedBlock] in block order. The native call alone is
     the ``t1.native`` stage of ``sink`` (items = blocks)."""
     n = len(nbps)
     lib = native.load()
-    cls = np.array([_BAND_CLS[b] for b in bands], dtype=np.int32)
     if lib is not None and n:
+        cls = np.fromiter(map(_BAND_CLS.__getitem__, bands), np.int32, n)
         # Bind every converted array to a local: .ctypes.data of an
         # unnamed temporary is a dangling pointer by call time.
         payload = np.ascontiguousarray(payload, dtype=np.uint8)
@@ -122,7 +178,7 @@ def encode_packed(payload: np.ndarray, offsets: np.ndarray,
                 n, payload.ctypes.data, offs.ctypes.data,
                 nbps_c.ctypes.data, floors_c.ctypes.data, hs_c.ctypes.data,
                 ws_c.ctypes.data, cls.ctypes.data, threads)
-        return _collect(lib, handle, n)
+        return _collect(lib, handle, n, exact)
     _note_python(n)
     out = []
     for i in range(n):
@@ -135,6 +191,8 @@ def encode_packed(payload: np.ndarray, offsets: np.ndarray,
                                            int(hs[i]), int(ws[i]))
         out.append(t1.encode_block(mags, negs, bands[i],
                                    floor=int(floors[i])))
+    if exact is not None:
+        correct_distortions(out, exact)
     return out
 
 
@@ -179,12 +237,13 @@ def encode_blocks(specs: list) -> list:
     return _collect(lib, handle, n)
 
 
-def encode_cxd(streams) -> list:
+def encode_cxd(streams, exact=None) -> list:
     """MQ replay of precomputed device CX/D streams (codec/cxd.py) —
     the host half of the BUCKETEER_DEVICE_CXD Tier-1 split. Native
     thread pool when available, pure-Python MQEncoder replay otherwise.
-    Returns [t1.CodedBlock] in block order, byte-identical to what
-    encode_packed would have produced from the same coefficients."""
+    ``exact`` as for :func:`encode_packed`. Returns [t1.CodedBlock] in
+    block order, byte-identical to what encode_packed would have
+    produced from the same coefficients."""
     from . import cxd
 
     n = len(streams.nbps)
@@ -209,7 +268,7 @@ def encode_cxd(streams) -> list:
             nbps_c.ctypes.data, p_offs.ctypes.data, p_types.ctypes.data,
             p_planes.ctypes.data, p_nsyms.ctypes.data,
             p_dists.ctypes.data, threads)
-        return _collect(lib, handle, n)
+        return _collect(lib, handle, n, exact)
 
     _note_python(n)
     out = []
@@ -224,4 +283,6 @@ def encode_cxd(streams) -> list:
             syms, int(streams.nbps[b]), p1 - p0, streams.pass_types[p0:p1],
             streams.pass_planes[p0:p1], streams.pass_nsyms[p0:p1],
             streams.pass_dists[p0:p1]))
+    if exact is not None:
+        correct_distortions(out, exact)
     return out

@@ -30,7 +30,7 @@ LOG = logging.getLogger(__name__)
 
 _DIR = Path(__file__).resolve().parent
 _LIB_PATH = _DIR / "libbucketeer_t1.so"
-_ABI_VERSION = 5     # must match t1_abi_version() in t1.cpp
+_ABI_VERSION = 6     # must match t1_abi_version() in t1.cpp
 _lib = None
 _tried = False
 # Held across the first load and its build: the host Tier-1 pool calls
@@ -158,9 +158,8 @@ def _load_locked():
         [ctypes.c_void_p] * 11 + [ctypes.c_int]
     lib.t1_block_sizes.restype = None
     lib.t1_block_sizes.argtypes = [ctypes.c_void_p] + [ctypes.c_void_p] * 3
-    lib.t1_block_get.restype = None
-    lib.t1_block_get.argtypes = [ctypes.c_void_p, ctypes.c_int] + \
-        [ctypes.c_void_p] * 5
+    lib.t1_result_copy.restype = None
+    lib.t1_result_copy.argtypes = [ctypes.c_void_p] * 6
     lib.t1_result_free.restype = None
     lib.t1_result_free.argtypes = [ctypes.c_void_p]
     _lib = lib

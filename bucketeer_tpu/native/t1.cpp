@@ -700,7 +700,7 @@ extern "C" {
 // refuses a library whose version doesn't match, so a stale prebuilt
 // .so (deployment images may prune t1.cpp) fails loudly instead of
 // misreading the new argument layout.
-int32_t t1_abi_version() { return 5; }
+int32_t t1_abi_version() { return 6; }
 
 T1Result* t1_encode_blocks(int n_blocks,
                            const uint32_t* mags, const uint8_t* negs,
@@ -842,15 +842,24 @@ void t1_block_sizes(T1Result* r, int32_t* nbps, int32_t* npasses,
     }
 }
 
-void t1_block_get(T1Result* r, int i, uint8_t* data, int32_t* ptype,
-                  int32_t* pplane, int64_t* plen, double* pdist) {
-    const BlockOut& b = r->blocks[i];
-    if (!b.data.empty()) std::memcpy(data, b.data.data(), b.data.size());
-    for (size_t k = 0; k < b.passes.size(); k++) {
-        ptype[k] = b.passes[k].type;
-        pplane[k] = b.passes[k].plane;
-        plen[k] = b.passes[k].cum_len;
-        pdist[k] = b.passes[k].dist;
+// Copies every block's result into caller-owned flat arrays in one
+// call: block i's bytes land at data + (sum of nbytes[j < i]) and its
+// passes at index (sum of npasses[j < i]) of the four pass arrays, the
+// prefix sums of what t1_block_sizes reports.
+void t1_result_copy(T1Result* r, uint8_t* data, int32_t* ptype,
+                    int32_t* pplane, int64_t* plen, double* pdist) {
+    int64_t b0 = 0, k0 = 0;
+    for (const BlockOut& b : r->blocks) {
+        if (!b.data.empty())
+            std::memcpy(data + b0, b.data.data(), b.data.size());
+        b0 += (int64_t)b.data.size();
+        for (const PassRec& pr : b.passes) {
+            ptype[k0] = pr.type;
+            pplane[k0] = pr.plane;
+            plen[k0] = pr.cum_len;
+            pdist[k0] = pr.dist;
+            k0++;
+        }
     }
 }
 
