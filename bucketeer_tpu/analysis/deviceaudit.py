@@ -28,8 +28,8 @@ for facts the AST cannot see:
 - **program manifest** — ``.graftaudit-manifest.json`` records, per
   program × bucket, a stable fingerprint (sha256 of the lowered text)
   plus an op histogram. ``--audit`` diffs against the checked-in file
-  exactly like ``bench_gate.py`` gates throughput — but statically, on
-  every PR, with no device. Regenerate after an intentional change
+  the way a benchmark compares a run with its parent — but statically,
+  on every PR, with no device. Regenerate after an intentional change
   with ``python -m bucketeer_tpu.analysis --write-manifest``.
 
 The d2h whitelist validation closes the loop from the other side:
@@ -152,16 +152,12 @@ def registry() -> list:
 
     entries.append(AuditProgram(
         "frontend.rows/gray8-lossless-64x64-L2/B1",
-        lambda: frontend.frontend_program(plan_g, p_g, "rows")
+        lambda: frontend.frontend_program(plan_g, p_g)
         + ([sds((1, 64, 64, 1), jnp.int32)],)))
     entries.append(AuditProgram(
         "frontend.rows/rgb8-lossy-64x64-L2/B2",
-        lambda: frontend.frontend_program(plan_c, p_c, "rows")
+        lambda: frontend.frontend_program(plan_c, p_c)
         + ([sds((2, 64, 64, 3), jnp.int32)],)))
-    entries.append(AuditProgram(
-        "frontend.cxd/gray8-lossless-64x64-L2/B1",
-        lambda: frontend.frontend_program(plan_g, p_g, "cxd")
-        + ([sds((1, 64, 64, 1), jnp.int32)],)))
     entries.append(AuditProgram(
         "pipeline.transform/gray8-lossless-64x64-L2/B1",
         lambda: transform_program(plan_g)
@@ -180,13 +176,13 @@ def registry() -> list:
         "cxd.scan.pallas/L2/N1",
         lambda: cxd.cxd_program(2, pallas=True, interpret=True)
         + (cxd_args(1),)))
-    # Fused device Tier-1 (BUCKETEER_DEVICE_MQ): CX/D context modeling
-    # chained straight into the MQ coder inside one program, so the
-    # (N, max_syms) symbol buffer never exists in HBM (the
-    # perf-hbm-roundtrip the old two-program chain carried). The MQ
-    # half's trip count is the realized symbol cursor — a dynamic
-    # while the static cost model reports as unknown_trips rather
-    # than a readable depth.
+    # Fused device Tier-1 (the tensor codec's device backend): CX/D
+    # context modeling chained straight into the MQ coder inside one
+    # program, so the (N, max_syms) symbol buffer never exists in HBM
+    # (the perf-hbm-roundtrip the old two-program chain carried). The
+    # MQ half's trip count is the realized symbol cursor — a dynamic
+    # while the static cost model reports as unknown_trips rather than
+    # a readable depth.
     entries.append(AuditProgram(
         "cxdmq.fused/L2/N1",
         lambda: cxd.fused_program(2, pallas=False) + (cxd_args(1),)))

@@ -34,9 +34,9 @@ StableHLO); ``while`` carries are charged at the materialization
 boundary every trip, which a VMEM-resident Pallas kernel genuinely
 avoids — that conservatism is what makes the per-symbol scans score as
 catastrophically memory-bound, which is the point. Machine numbers are
-order-of-magnitude; ``bench.py`` records the model's prediction error
-against every measured ``tier1_split`` so the model is calibrated by
-use, not trusted.
+order-of-magnitude; :func:`tier1_prediction` states the model's
+device-Tier-1 symbol rate so a chip measurement of the fused program
+can calibrate it — it is not to be trusted uncalibrated.
 
 The module also owns the **workload-shape histogram**: the codec's
 pow-2 bucket seams (``frontend.dispatch_frontend``, ``cxd.run_cxd`` /
@@ -121,28 +121,6 @@ MACHINES = {
                         ici_bandwidth=1.0e12, n_devices=8),
 }
 DEFAULT_MACHINE = "tpu_v4"
-
-# jax ``device_kind`` -> MACHINES key: the live backend picks its model
-# by what JAX reports, never by borrowing another chip's.
-DEVICE_KINDS = {"TPU v4": "tpu_v4", "TPU v5 lite": "tpu_v5e",
-                "cpu": "cpu"}
-
-
-class UnknownDevice(LookupError):
-    """No machine model for this accelerator kind."""
-
-
-def machine_for_device_kind(kind: str) -> MachineModel:
-    """The machine model of the device JAX reports as ``kind``; raises
-    :class:`UnknownDevice` for a kind with no entry."""
-    try:
-        return MACHINES[DEVICE_KINDS[kind]]
-    except KeyError:
-        raise UnknownDevice(
-            f"no machine model for device_kind {kind!r}: add its "
-            "published peaks to graftcost.MACHINES and DEVICE_KINDS"
-        ) from None
-
 
 # --- StableHLO types ------------------------------------------------------
 
@@ -847,10 +825,9 @@ def tier1_prediction() -> dict:
     block, L=2). The fused MQ half's trip count is dynamic (realized
     cursor), so the roofline covers the static CX/D scan and the MQ
     sequential term is added as ``PREDICTION_SYMS / MQ_UNROLL`` trips
-    of the machine's seq-step overhead. ``bench.py`` emits this beside
-    the measured ``tier1_split`` symbols/s and records the prediction
-    error — the calibration loop that keeps the machine numbers
-    honest. Lowers one program on first use (cached per process)."""
+    of the machine's seq-step overhead. Compare it with a measured
+    device-Tier-1 symbol rate to calibrate the machine numbers. Lowers
+    one program on first use (cached per process)."""
     if _PREDICTION_CACHE:
         return dict(_PREDICTION_CACHE)
     from . import deviceaudit

@@ -528,7 +528,7 @@ def device_pool_storm(ctl):
     jobs = []
     with sched_b._dq_cv:
         for plan, prio in wave2:
-            job = _DeviceJob(plan, tiles, "rows", 1, priority=prio)
+            job = _DeviceJob(plan, tiles, 1, priority=prio)
             job.seq = next(sched_b._dseq)
             sched_b._djobs.append(job)
             jobs.append(job)
@@ -559,8 +559,8 @@ def device_pool_storm(ctl):
     started[("c", 0)].wait()
     hc1 = ctl.spawn(lambda: hold_client(sched_c, plan_c1), "hold-c1")
     started[("c", 1)].wait()
-    queued = [_DeviceJob(("c", "q0"), tiles, "rows", 1),
-              _DeviceJob(("c", "q1"), tiles, "rows", 1),
+    queued = [_DeviceJob(("c", "q0"), tiles, 1),
+              _DeviceJob(("c", "q1"), tiles, 1),
               _TensorJob(np.zeros((3, 8), np.float32),
                          np.zeros(3, np.int32), "device", 3)]
     with sched_c._dq_cv:
@@ -671,10 +671,8 @@ def batch_fanout_vs_read(ctl):
     started[("a", 0)].wait()
     dq_jobs = [_DequantJob(True, (1.0,), bands, expected=2)
                for _ in range(2)]
-    rd_job = _DeviceJob(("read",), tiles, "rows", 1,
-                        priority=PRIORITY_READ)
-    bulk_job = _DeviceJob(("bulk",), tiles, "rows", 1,
-                          priority=PRIORITY_BATCH)
+    rd_job = _DeviceJob(("read",), tiles, 1, priority=PRIORITY_READ)
+    bulk_job = _DeviceJob(("bulk",), tiles, 1, priority=PRIORITY_BATCH)
     with sched_a._dq_cv:
         # Bulk encode first in FIFO order: only priority can put the
         # read in front and the dequants in between.

@@ -8,7 +8,7 @@ moves the whole of Tier-1 onto the device:
 - **CX/D scan** — per block, the exact ordered (context, decision)
   symbol sequence the MQ coder consumes, plus per-pass symbol counts
   (PCRD truncation boundaries) and exact per-pass distortions.
-- **MQ coder** (``BUCKETEER_DEVICE_MQ``) — a byte-emitting scan
+- **MQ coder** (:func:`run_device_mq`) — a byte-emitting scan
   replicating the host ``MQEncoder`` register for register, fused with
   the CX/D scan into one device program (:func:`fused_program`) so the
   symbol buffer never round-trips HBM.
@@ -68,7 +68,6 @@ the 0xFF carry paths, flush, the trailing-0xFF drop and the per-pass
 from __future__ import annotations
 
 import os
-import time
 from dataclasses import dataclass
 from functools import lru_cache, partial
 from types import SimpleNamespace
@@ -869,7 +868,7 @@ def _fetch_block_rows(rows_dev, rows_needed: np.ndarray, rpb: int,
     return frontend.gather_rows(rows_dev, src, row_bytes), row_offsets
 
 
-# --- the device MQ coder (BUCKETEER_DEVICE_MQ) --------------------------
+# --- the device MQ coder (run_device_mq) ---------------------------------
 #
 # A byte-emitting scan over the CX/D symbol buffer replicating the host
 # MQEncoder register for register: A (16-bit interval), C (32-bit code,
@@ -1204,16 +1203,10 @@ def _compiled_fused(L: int):
 @dataclass
 class MqDeviceResult:
     """One chunk's device-MQ outcome: finished code-blocks plus the
-    segment timings/volumes the encoder's metrics report. With the
-    fused program the device cannot split context modeling from MQ
-    coding; ``cxd_s`` carries the fused launches (dispatch + the small
-    cursor/snapshot transfers) and ``mq_s`` the byte-segment fetch."""
+    symbols coded and the bytes they produced."""
     blocks: list               # [t1.CodedBlock]
     total_syms: int
     total_bytes: int
-    cxd_s: float               # fused device launches
-    mq_s: float                # byte-segment fetch
-    host_s: float              # host assembly (the entire host share)
 
 
 def assemble_mq_blocks(nbps: np.ndarray, floors: np.ndarray,
@@ -1222,8 +1215,7 @@ def assemble_mq_blocks(nbps: np.ndarray, floors: np.ndarray,
                        row_offsets: np.ndarray) -> list:
     """Host assembly of device-MQ outputs into ``t1.CodedBlock``s — the
     whole host share of Tier-1 in device-MQ mode (no MQ replay, no
-    context modeling; bench.py re-times exactly this to measure the
-    host-work reduction).
+    context modeling).
 
     ``snaps``: (n, L, 3) per-pass byte counts indexed by plane offset
     from each block's MSB; ``dlens``: (n,) final data lengths;
@@ -1271,18 +1263,15 @@ def run_device_mq(blocks_dev, nbps: np.ndarray, floors: np.ndarray,
     n = len(nbps)
     out = [t1.CodedBlock(b"", 0) for _ in range(n)]
     tot_syms = tot_bytes = 0
-    t_cxd = t_mq = t_host = 0.0
     for L, idxs, g, args in _group_launches(blocks_dev, nbps, floors,
                                             bandnames, hs, ws,
                                             frac_bits):
         cap = mq_capacity(max_syms(L))
 
-        t0 = time.perf_counter()
         rows, snaps, dlen, dh, dl, cur, curb = _compiled_fused(L)(*args)
         snaps_h, dlen_h, dh_h, dl_h, cur_h, curb_h = (
             np.asarray(jax.device_get(x))[:g]
             for x in (snaps, dlen, dh, dl, cur, curb))
-        t_cxd += time.perf_counter() - t0
 
         if g:
             _check_sym_overflow(int(cur_h.max()), L)
@@ -1293,20 +1282,16 @@ def run_device_mq(blocks_dev, nbps: np.ndarray, floors: np.ndarray,
                 "expanded past the 4-bit/symbol budget")
         dist = (dh_h.astype(np.float64) + dl_h.astype(np.float64)) / 4.0
 
-        t0 = time.perf_counter()
         # Row-granular byte fetch: only the rows each live block filled
         # (the block's segment includes the leading dummy pre-byte).
         payload, row_offs = _fetch_block_rows(
             rows, -(-(dlen_h + 1) // MQ_ROW_BYTES) * (dlen_h > 0),
             cap // MQ_ROW_BYTES, MQ_ROW_BYTES)
-        t_mq += time.perf_counter() - t0
 
-        t0 = time.perf_counter()
         blocks_g = assemble_mq_blocks(nbps[idxs], floors[idxs], snaps_h,
                                       dlen_h, dist, payload, row_offs)
         for k, i in enumerate(idxs):
             out[int(i)] = blocks_g[k]
-        t_host += time.perf_counter() - t0
         tot_syms += int(cur_h.sum())
         tot_bytes += int(dlen_h.sum())
-    return MqDeviceResult(out, tot_syms, tot_bytes, t_cxd, t_mq, t_host)
+    return MqDeviceResult(out, tot_syms, tot_bytes)

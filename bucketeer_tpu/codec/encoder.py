@@ -39,16 +39,14 @@ import numpy as np
 
 from .. import obs
 from ..analysis.contracts import contract
-from ..config import truthy as cfg_truthy
 from . import codestream as cs
-from . import cxd as cxd_mod
 from . import frontend
 from . import jp2 as jp2box
 from . import rate as rate_mod
 from . import t1, t1_batch, t2
 from .dwt import synthesis_gains
 from .pipeline import TilePlan, make_plan
-from .quant import FRAC_BITS, GUARD_BITS, SubbandQuant
+from .quant import GUARD_BITS, SubbandQuant
 
 CBLK_EXP = 6  # 64x64 code-blocks (reference recipe Cblk={64,64})
 
@@ -68,46 +66,6 @@ def _overlap_tiles() -> int:
     """Tiles per pipeline chunk. Power-of-two keeps the batch bucketing
     (pipeline._bucket) from compiling extra program variants."""
     return max(1, int(os.environ.get("BUCKETEER_OVERLAP_TILES", "8")))
-
-
-def _device_cxd(params: EncodeParams) -> bool:
-    """Whether this encode runs the device-CX/D Tier-1 split: the
-    explicit EncodeParams.device_cxd wins, else BUCKETEER_DEVICE_CXD
-    (config.truthy spellings)."""
-    if params.device_cxd is not None:
-        return bool(params.device_cxd)
-    return cfg_truthy(os.environ.get("BUCKETEER_DEVICE_CXD"))
-
-
-def _device_mq(params: EncodeParams) -> bool:
-    """Whether this encode runs Tier-1 entirely on device (the fused
-    CX/D + MQ program, codec/cxd.py run_device_mq): the explicit
-    EncodeParams.device_mq wins, else BUCKETEER_DEVICE_MQ, off by
-    default on every platform. On a TPU v5e the fused program took
-    50.0 s of a warm 2048² RGB lossy encode where the native host coder
-    took 0.98 s end to end, byte-identical (my chip run, PR 21), so the
-    host coder is the default Tier-1 on the chip too."""
-    if params.device_mq is not None:
-        return bool(params.device_mq)
-    return cfg_truthy(os.environ.get("BUCKETEER_DEVICE_MQ"))
-
-
-class _ImmediateResult:
-    """Future-quack for Tier-1 work finished inline. Device-MQ mode
-    bypasses the host Tier-1 pool entirely — the blocks come back
-    assembled from the device fetch — but the pipeline's ordered
-    reassembly (``futs`` submission order) stays uniform."""
-
-    __slots__ = ("_value",)
-
-    def __init__(self, value) -> None:
-        self._value = value
-
-    def done(self) -> bool:
-        return True
-
-    def result(self):
-        return self._value
 
 
 # Optional per-stage timing/counter sink (server.metrics.Metrics). The
@@ -137,13 +95,9 @@ _SERVICES = threading.local()
 
 @dataclass
 class _PipelineServices:
-    dispatch: object          # callable(plan, tiles, mode=...) -> pending
+    dispatch: object          # callable(plan, tiles) -> pending
     pool: object              # shared executor; NOT shut down per encode
     check: object = None      # callable raising on deadline/cancel
-    t1_launch: object = None  # callable(stage_fn, payload) -> stage
-                              # result; the scheduler's pipeline-stage
-                              # hook for the fused CX/D+MQ program
-                              # (None = run inline on this thread)
 
 
 def current_services() -> _PipelineServices | None:
@@ -151,12 +105,11 @@ def current_services() -> _PipelineServices | None:
 
 
 @contextlib.contextmanager
-def pipeline_services(dispatch=None, pool=None, check=None,
-                      t1_launch=None):
+def pipeline_services(dispatch=None, pool=None, check=None):
     """Install scheduler-owned pipeline services for encodes running on
     this thread (the scheduler wraps each admitted request in this)."""
     prev = getattr(_SERVICES, "svc", None)
-    _SERVICES.svc = _PipelineServices(dispatch, pool, check, t1_launch)
+    _SERVICES.svc = _PipelineServices(dispatch, pool, check)
     try:
         yield
     finally:
@@ -179,20 +132,6 @@ class EncodeParams:
     tparts_r: bool = False             # tile-part per resolution (ORGtparts=R)
     mct: str = "auto"                  # multi-component transform: auto|on|off
     comment: str = "bucketeer-tpu jp2 encoder"
-    # Tier-1 split: run EBCOT context modeling on the device and replay
-    # the CX/D streams through the host MQ coder (codec/cxd.py +
-    # native t1_encode_cxd). None = the BUCKETEER_DEVICE_CXD env flag
-    # decides; the converter wires the bucketeer.tpu.device.cxd config
-    # key through here. Byte-identical output either way.
-    device_cxd: bool | None = None
-    # Full Tier-1 on device: chain the MQ arithmetic coder after the
-    # CX/D scan (codec/cxd.py run_device_mq) so the device emits
-    # finished per-pass byte segments and the host does Tier-2 assembly
-    # only — no MQ replay, no host Tier-1 pool. None = the
-    # BUCKETEER_DEVICE_MQ env flag decides; the converter wires the
-    # bucketeer.tpu.device.mq config key through here. Implies the
-    # CX/D split. Byte-identical output in every mode.
-    device_mq: bool | None = None
 
     @classmethod
     def kakadu_recipe(cls, lossless: bool,
@@ -865,17 +804,11 @@ def encode_array(img: np.ndarray, bitdepth: int = 8,
     chunks, tile_records, qcd_values = _build_chunks(
         groups, plans, used_mct, gains, weight_of_slot, norms)
 
-    use_mq = _device_mq(params)
-    use_cxd = use_mq or _device_cxd(params)
-    frac_bits = 0 if params.lossless else FRAC_BITS
-    tm = {"device": 0.0, "host": 0.0, "cxd": 0.0, "mq": 0.0,
-          "mq_dev": 0.0}
+    tm = {"device": 0.0, "host": 0.0}
     # The shared scheduler pool may run two of this encode's chunks
     # concurrently (the private executor never did); serialize the
     # timing accumulator so segments stay exact.
     tm_lock = threading.Lock()
-    n_syms = [0]
-    n_mq_bytes = [0]
     floor_lam = [0.0]
     t_wall0 = time.perf_counter()
 
@@ -902,8 +835,7 @@ def encode_array(img: np.ndarray, bitdepth: int = 8,
             batch = np.stack([img[y0:y0 + chunk.plan.tile_h,
                                   x0:x0 + chunk.plan.tile_w]
                               for _, y0, x0 in chunk.members])
-            mode = "mq" if use_mq else ("cxd" if use_cxd else "rows")
-            chunk.pending = dispatch_fn(chunk.plan, batch, mode=mode)
+            chunk.pending = dispatch_fn(chunk.plan, batch)
         _tm_add("device", time.perf_counter() - t0)
 
     def resolve(chunk: _Chunk) -> None:
@@ -943,19 +875,6 @@ def encode_array(img: np.ndarray, bitdepth: int = 8,
         _tm_add("host", st.seconds)
         return blocks
 
-    def host_replay(chunk: _Chunk, streams, pixels: int,
-                    submitted: float) -> list:
-        """The CX/D-mode host half: pure MQ replay of the device's
-        symbol streams — no context modeling left on the host."""
-        obs.waited(_metrics_sink, "encode.t1_wait", submitted, pixels)
-        with obs.stage(_metrics_sink, "encode.host_code", pixels,
-                       span_name="encode.mq_replay",
-                       blocks=len(chunk.dests)) as st:
-            blocks = t1_batch.encode_cxd(streams, exact=exact_of(chunk))
-        _tm_add("host", st.seconds)
-        _tm_add("mq", st.seconds)
-        return blocks
-
     def fetch_and_submit(pool, chunk: _Chunk, floors: np.ndarray,
                          futs: list, release_rows: bool,
                          first: bool) -> None:
@@ -963,68 +882,13 @@ def encode_array(img: np.ndarray, bitdepth: int = 8,
         count its source pixels (a floor rerun adds time, not pixels)."""
         pixels = chunk.pixels if first else 0
         t0 = time.perf_counter()
-        if use_mq:
-            # Tier-1 never touches the host: the device runs CX/D and
-            # the MQ coder back to back (symbols stay in HBM between
-            # the two programs) and ships finished byte segments; the
-            # shared host Tier-1 pool is bypassed entirely.
-            def t1_stage(blocks_dev):
-                return cxd_mod.run_device_mq(
-                    blocks_dev, chunk.fres.nbps, floors,
-                    chunk.bandnames, chunk.hs, chunk.ws,
-                    chunk.fres.layout.P, frac_bits)
-
-            with obs.span("encode.t1_device", blocks=len(chunk.dests)):
-                if svc is not None and svc.t1_launch is not None:
-                    # Pipeline-stage mapping: the scheduler stages the
-                    # fused program onto its Tier-1 device subset (the
-                    # payload is re-committed to the worker's core);
-                    # the span here covers staging wait + execution.
-                    res = svc.t1_launch(t1_stage, chunk.fres.blocks)
-                else:
-                    res = t1_stage(chunk.fres.blocks)
-            _tm_add("device", res.cxd_s + res.mq_s)
-            _tm_add("cxd", res.cxd_s)
-            _tm_add("mq_dev", res.mq_s)
-            n_syms[0] += res.total_syms
-            n_mq_bytes[0] += res.total_bytes
-            if release_rows:
-                chunk.fres.blocks = None    # free the HBM staging buffer
-            blocks = res.blocks
-            th0 = time.perf_counter()
-            if not params.lossless:
-                t1_batch.correct_distortions(blocks, exact_of(chunk))
-            # The whole host share: assembly + distortion correction.
-            host_s = res.host_s + time.perf_counter() - th0
-            _tm_add("host", host_s)
-            if _metrics_sink is not None:
-                _metrics_sink.record("encode.host_code", host_s,
-                                     pixels=pixels)
-            # No back-pressure check: nothing is in flight — every
-            # entry this branch appends is already resolved.
-            futs.append(_ImmediateResult(blocks))
-            return
-        if use_cxd:
-            with obs.span("encode.cxd_device",
-                          blocks=len(chunk.dests)):
-                streams = cxd_mod.run_cxd(
-                    chunk.fres.blocks, chunk.fres.nbps, floors,
-                    chunk.bandnames, chunk.hs, chunk.ws,
-                    chunk.fres.layout.P, frac_bits)
-            dt = time.perf_counter() - t0
-            _tm_add("device", dt)
-            _tm_add("cxd", dt)
-            n_syms[0] += streams.total_syms
-            if release_rows:
-                chunk.fres.blocks = None    # free the HBM staging buffer
-        else:
-            with obs.stage(_metrics_sink, "encode.fetch_payload", pixels):
-                src, offsets = frontend.payload_plan(
-                    chunk.fres.nbps, floors, chunk.fres.layout.P)
-                payload = frontend.fetch_payload(chunk.fres, src)
-            _tm_add("device", time.perf_counter() - t0)
-            if release_rows:
-                chunk.fres.rows = None  # free the staging buffer in HBM
+        with obs.stage(_metrics_sink, "encode.fetch_payload", pixels):
+            src, offsets = frontend.payload_plan(
+                chunk.fres.nbps, floors, chunk.fres.layout.P)
+            payload = frontend.fetch_payload(chunk.fres, src)
+        _tm_add("device", time.perf_counter() - t0)
+        if release_rows:
+            chunk.fres.rows = None  # free the staging buffer in HBM
         # Back-pressure: at most HOST_QUEUE_DEPTH unfinished host jobs
         # so payload staging stays bounded.
         live = [f for f in futs if not f.done()]
@@ -1033,12 +897,8 @@ def encode_array(img: np.ndarray, bitdepth: int = 8,
         # obs.bind: the shared pool's threads don't inherit contextvars;
         # rebind the request's trace context around the host-coding item.
         submitted = time.perf_counter()
-        if use_cxd:
-            futs.append(pool.submit(obs.bind(host_replay), chunk,
-                                    streams, pixels, submitted))
-        else:
-            futs.append(pool.submit(obs.bind(host_code), chunk, floors,
-                                    payload, offsets, pixels, submitted))
+        futs.append(pool.submit(obs.bind(host_code), chunk, floors,
+                                payload, offsets, pixels, submitted))
 
     def chunk_floors(margin: float) -> list:
         if target is None:
@@ -1148,29 +1008,6 @@ def encode_array(img: np.ndarray, bitdepth: int = 8,
     if _metrics_sink is not None:
         _metrics_sink.record("encode.device_dispatch", tm["device"],
                              pixels=h * w)
-        if use_mq:
-            # Full-device Tier-1 segments: context modeling, the MQ
-            # coder (items=bytes -> bytes/s), and their sum (items=
-            # symbols -> symbols/s). encode.host_code, recorded per
-            # chunk, is the whole host share (block assembly only).
-            _metrics_sink.record("encode.cxd_device", tm["cxd"],
-                                 pixels=h * w)
-            _metrics_sink.record("encode.mq_device", tm["mq_dev"],
-                                 pixels=h * w, items=n_mq_bytes[0])
-            _metrics_sink.record("encode.t1_device_total",
-                                 tm["cxd"] + tm["mq_dev"],
-                                 pixels=h * w, items=n_syms[0])
-            _metrics_sink.count("encode.cxd_symbols", n_syms[0])
-            _metrics_sink.count("encode.mq_device_bytes", n_mq_bytes[0])
-        elif use_cxd:
-            # The Tier-1 split's own segments: device context modeling
-            # vs host MQ replay, plus symbol throughput (/metrics shows
-            # items_per_s on the replay stage).
-            _metrics_sink.record("encode.cxd_device", tm["cxd"],
-                                 pixels=h * w)
-            _metrics_sink.record("encode.mq_replay", tm["mq"],
-                                 pixels=h * w, items=n_syms[0])
-            _metrics_sink.count("encode.cxd_symbols", n_syms[0])
         _metrics_sink.record_overlap("encode", tm["device"], tm["host"],
                                      wall_s, pixels=h * w)
 

@@ -123,25 +123,20 @@ def _pack_bits(bits: jnp.ndarray) -> jnp.ndarray:
     return (b * w).sum(axis=-1).astype(jnp.uint8).reshape(n, ROW_BYTES)
 
 
-def _frontend_body(plan: TilePlan, P: int, frac_bits: int, mode: str,
-                   step_map, batch: jnp.ndarray):
-    """The full device program for one tile batch.
-
-    ``mode``: "rows" packs per-plane bitmaps for the host coder's packed
-    path; "cxd" skips the packing and returns the blockified int32
-    coefficient planes instead — they stay in HBM as the input of the
-    CX/D context-modeling stage (codec/cxd.py)."""
+def _frontend_body(plan: TilePlan, P: int, frac_bits: int, step_map,
+                   batch: jnp.ndarray):
+    """The full device program for one tile batch: per-plane bitmaps
+    for the host coder's packed path, plus the per-block statistics."""
     planes = _transform_batch(plan, step_map, batch)
     blocks = _blockify(planes, plan)
     mag_fp = jnp.abs(blocks)
     idx = (mag_fp >> frac_bits).astype(jnp.uint32)
     maxidx = idx.max(axis=(1, 2)).astype(jnp.int32)
 
-    if mode == "rows":
-        rows = [_pack_bits(blocks < 0)]      # sign plane first
-        for p in range(P):
-            rows.append(_pack_bits((idx >> p) & 1))
-        rows = jnp.stack(rows, axis=1)       # (N, P+1, 512)
+    rows = [_pack_bits(blocks < 0)]          # sign plane first
+    for p in range(P):
+        rows.append(_pack_bits((idx >> p) & 1))
+    rows = jnp.stack(rows, axis=1)           # (N, P+1, 512)
 
     if frac_bits:
         tv = mag_fp.astype(jnp.float32) * (1.0 / (1 << frac_bits))
@@ -171,14 +166,12 @@ def _frontend_body(plan: TilePlan, P: int, frac_bits: int, mode: str,
         refd.append(rd.sum(axis=(1, 2), dtype=jnp.float32))
     stats = (maxidx, jnp.stack(newsig, 1), jnp.stack(sigd, 1),
              jnp.stack(refd, 1))
-    if mode == "rows":
-        return rows.reshape(-1, ROW_BYTES), stats
-    return blocks, stats
+    return rows.reshape(-1, ROW_BYTES), stats
 
 
-def frontend_program(plan: TilePlan, P: int, mode: str = "rows"):
-    """(traceable fn, device donate_argnums) for one front-end variant —
-    the exact construction :func:`_compiled_frontend` jits, shared with
+def frontend_program(plan: TilePlan, P: int):
+    """(traceable fn, device donate_argnums) for the front-end — the
+    exact construction :func:`_compiled_frontend` jits, shared with
     the device audit (analysis/deviceaudit.py) so the audited artifact
     is the shipped one.
 
@@ -192,23 +185,20 @@ def frontend_program(plan: TilePlan, P: int, mode: str = "rows"):
     frac_bits = 0 if plan.lossless else FRAC_BITS
     step_map = jnp.asarray(_step_map(plan)) if not plan.lossless else None
     fn = retrace.instrument(
-        "frontend", partial(_frontend_body, plan, P, frac_bits, mode,
-                            step_map))
+        "frontend", partial(_frontend_body, plan, P, frac_bits, step_map))
     return fn, ()
 
 
 @lru_cache(maxsize=256)
-def _compiled_frontend(plan: TilePlan, P: int, mode: str = "rows"):
-    fn, donate = frontend_program(plan, P, mode)
+def _compiled_frontend(plan: TilePlan, P: int):
+    fn, donate = frontend_program(plan, P)
     return jax.jit(fn, donate_argnums=donate_argnums_if_supported(*donate))
 
 
 @dataclass
 class FrontendResult:
     """Per tile-batch device output. ``rows`` stays on device until
-    fetch_payload pulls the compacted subset. In CX/D mode (``mode=
-    "cxd"``) ``rows`` is None and ``blocks`` holds the blockified int32
-    coefficient planes instead — the input of codec/cxd.py.
+    fetch_payload pulls the compacted subset.
 
     ``block_base``: first block's index within the shared device
     ``rows`` array. Non-zero when this result is one request's window
@@ -222,7 +212,6 @@ class FrontendResult:
     newsig: np.ndarray    # (n_blocks, P) int32
     sigd: np.ndarray      # (n_blocks, P) float32
     refd: np.ndarray      # (n_blocks, P) float32
-    blocks: object = None  # jax array (B*n_per_tile, 64, 64) int32
     block_base: int = 0   # offset into the shared rows array (blocks)
 
     @property
@@ -241,9 +230,8 @@ class PendingFrontend:
     packed payload is Tier-1 coded on host threads."""
     layout: FrontendLayout
     n_tiles: int
-    rows: object          # device array, stays in HBM (None in cxd mode)
+    rows: object          # device array, stays in HBM
     stats: object         # device array tuple (maxidx, newsig, sigd, refd)
-    blocks: object = None  # device array (cxd mode only)
     # Host copy of ``stats``, fetched once: a merged cross-request
     # launch (engine/scheduler.py) is resolved by several request
     # threads, each slicing its own window.
@@ -293,29 +281,21 @@ class PendingFrontend:
                 "overflow in the device front-end)")
         return FrontendResult(self.layout, n_tiles, self.rows, nbps,
                               newsig[sl], sigd[sl], refd[sl],
-                              blocks=self.blocks, block_base=off)
+                              block_base=off)
 
 
 @contract(shapes={"tiles": [("B", "h", "w"), ("B", "h", "w", "C")]},
           dtypes={"tiles": "number"})
 def dispatch_frontend(plan: TilePlan, tiles: np.ndarray,
-                      mode: str = "rows",
                       device=None, pool=()) -> PendingFrontend:
     """Queue transform + blockify + stats for a (B, h, w[, C]) tile
     batch on the device and return without waiting for the result.
-    ``mode="cxd"`` keeps the raw blockified coefficients on device for
-    the CX/D stage instead of packing bit-plane bitmaps; ``mode="mq"``
-    is the full-device Tier-1 chain (CX/D scan + MQ coder,
-    cxd.run_device_mq) — the front-end program is identical to "cxd"
-    (one compiled variant serves both; the modes diverge downstream),
-    the distinct name exists so the scheduler and metrics can tell the
-    pipelines apart. ``device`` (a ``jax.Device``) stages the batch
-    with a *committed* ``jax.device_put`` so the program — and every
-    downstream stage consuming its output, even from another thread —
-    runs on that core; None keeps default placement. ``pool``: the
-    devices of the scheduler's pool that ``device`` belongs to; on a
-    pool of more than one, the first launch of a shape readies its
-    programs on all of them (:func:`_ready_on_pool`)."""
+    ``device`` (a ``jax.Device``) stages the batch with a *committed*
+    ``jax.device_put`` so the program — and the payload gather consuming its
+    output, even from another thread — runs on that core; None keeps default
+    placement. ``pool``: the devices of the scheduler's pool that ``device``
+    belongs to; on a pool of more than one, the first launch of a shape
+    readies its programs on all of them (:func:`_ready_on_pool`)."""
     if tiles.ndim == 3:
         tiles = tiles[..., None]
     # Dtype audit at the host->device boundary: the device program's
@@ -335,20 +315,16 @@ def dispatch_frontend(plan: TilePlan, tiles: np.ndarray,
         tiles = np.concatenate(
             [tiles, np.zeros((pad,) + tiles.shape[1:], tiles.dtype)])
     layout = layout_for(plan)
-    prog_mode = "cxd" if mode == "mq" else mode
     # Committed placement (device_put) vs jnp.asarray matters: an
     # uncommitted array snaps back to the default device the moment a
-    # different thread consumes it; a committed one pins the whole
-    # downstream chain (gather, fused Tier-1) to the pool worker's core.
+    # different thread consumes it; a committed one pins the downstream
+    # payload gather to the pool worker's core.
     staged = (jax.device_put(tiles, device) if device is not None
               else jnp.asarray(tiles))
-    out, stats = _compiled_frontend(plan, layout.P, prog_mode)(staged)
+    rows, stats = _compiled_frontend(plan, layout.P)(staged)
     if len(pool) > 1:
-        _ready_on_pool(pool, device, plan, layout.P, prog_mode, staged,
-                       out if prog_mode == "rows" else None)
-    if prog_mode == "rows":
-        return PendingFrontend(layout, b, out, stats)
-    return PendingFrontend(layout, b, None, stats, blocks=out)
+        _ready_on_pool(pool, device, plan, layout.P, staged, rows)
+    return PendingFrontend(layout, b, rows, stats)
 
 
 # (program shape, device) pairs already made ready by _ready_on_pool:
@@ -357,37 +333,35 @@ _POOL_READY: set = set()
 _POOL_READY_LOCK = threading.Lock()
 
 
-def _ready_on_pool(pool, device, plan: TilePlan, P: int, mode: str,
-                   staged, rows) -> None:
+def _ready_on_pool(pool, device, plan: TilePlan, P: int, staged,
+                   rows) -> None:
     """Compile, or load from the persistent cache, the programs of a
     launch's shape on every device of the pool, once per shape and
     device, before the launch returns: the front-end on the devices
-    other than ``device`` (whose call just did it), and, given the
-    launch's ``rows``, the payload gather on all of them. A compiled
+    other than ``device`` (whose call just did it), and the payload
+    gather over the launch's ``rows`` on all of them. A compiled
     program is keyed by its device assignment, so without this a shape
     warmed on one device compiles again the first time another runs
     it, which the retrace counters (they count traces) do not show.
     The lowerings match the calls exactly: committed inputs carry the
     device's sharding, the gather's uncommitted index none."""
-    key = (plan, P, mode, staged.shape, staged.dtype)
+    key = (plan, P, staged.shape, staged.dtype)
     with _POOL_READY_LOCK:
         todo = [d for d in pool if (key, d) not in _POOL_READY]
         jobs = []
         for d in todo:
             on_d = jax.sharding.SingleDeviceSharding(d)
             if d != device:
-                jobs.append((_compiled_frontend(plan, P, mode),
+                jobs.append((_compiled_frontend(plan, P),
                              (jax.ShapeDtypeStruct(staged.shape,
                                                    staged.dtype,
                                                    sharding=on_d),)))
-            if rows is not None:
-                jobs.append((_compiled_gather(GATHER_CHUNK),
-                             (jax.ShapeDtypeStruct(rows.shape, rows.dtype,
-                                                   sharding=on_d),
-                              jax.ShapeDtypeStruct(
-                                  (GATHER_CHUNK,),
-                                  jax.dtypes.canonicalize_dtype(
-                                      np.int64)))))
+            jobs.append((_compiled_gather(GATHER_CHUNK),
+                         (jax.ShapeDtypeStruct(rows.shape, rows.dtype,
+                                               sharding=on_d),
+                          jax.ShapeDtypeStruct(
+                              (GATHER_CHUNK,),
+                              jax.dtypes.canonicalize_dtype(np.int64)))))
         if jobs:
             # One thread per program: XLA compiles outside the GIL.
             with ThreadPoolExecutor(len(jobs)) as ex:

@@ -5,13 +5,14 @@ Three kernels carry Tier-1 on device:
 - :mod:`.cxd_scan` — the stripe-parallel EBCOT CX/D scan (context
   modeling), keeping a code-block's significance state and symbol
   buffer resident in VMEM for the whole Mb-clamped plane walk instead
-  of letting XLA spill the batched scan state through HBM
-  (``BUCKETEER_DEVICE_CXD``).
-- :mod:`.fused_t1` — the production device-MQ path: the CX/D scan
+  of letting XLA spill the batched scan state through HBM (the tensor
+  codec's ``replay`` backend).
+- :mod:`.fused_t1` — the device-MQ path: the CX/D scan
   chained straight into the MQ arithmetic coder inside one kernel, the
   symbol buffer a kernel-local VMEM value that never touches HBM, so
   finished per-pass byte segments (not symbol streams, not work) are
-  all that ever reaches the host (``BUCKETEER_DEVICE_MQ``).
+  all that ever reaches the host (the tensor codec's ``device``
+  backend).
 - :mod:`.mq_scan` — the standalone MQ coder kernel, the per-block
   parity/oracle surface for the fused kernel's back half.
 

@@ -90,8 +90,8 @@ def _correct(pdist: np.ndarray, ptype: np.ndarray, pplane: np.ndarray,
 
 
 def correct_distortions(blocks: list, exact) -> None:
-    """:func:`_correct` on blocks already built: the device-MQ path's
-    (from ``cxd.run_device_mq``) and the pure-Python fallbacks'."""
+    """:func:`_correct` on blocks already built: the pure-Python
+    fallback of :func:`encode_packed`."""
     passes = [p for b in blocks for p in b.passes]
     if not passes:
         return
@@ -237,13 +237,12 @@ def encode_blocks(specs: list) -> list:
     return _collect(lib, handle, n)
 
 
-def encode_cxd(streams, exact=None) -> list:
+def encode_cxd(streams) -> list:
     """MQ replay of precomputed device CX/D streams (codec/cxd.py) —
-    the host half of the BUCKETEER_DEVICE_CXD Tier-1 split. Native
+    the host half of the tensor codec's device-CX/D backend. Native
     thread pool when available, pure-Python MQEncoder replay otherwise.
-    ``exact`` as for :func:`encode_packed`. Returns [t1.CodedBlock] in
-    block order, byte-identical to what encode_packed would have
-    produced from the same coefficients."""
+    Returns [t1.CodedBlock] in block order, byte-identical to what
+    encode_packed would have produced from the same coefficients."""
     from . import cxd
 
     n = len(streams.nbps)
@@ -268,7 +267,7 @@ def encode_cxd(streams, exact=None) -> list:
             nbps_c.ctypes.data, p_offs.ctypes.data, p_types.ctypes.data,
             p_planes.ctypes.data, p_nsyms.ctypes.data,
             p_dists.ctypes.data, threads)
-        return _collect(lib, handle, n, exact)
+        return _collect(lib, handle, n)
 
     _note_python(n)
     out = []
@@ -283,6 +282,4 @@ def encode_cxd(streams, exact=None) -> list:
             syms, int(streams.nbps[b]), p1 - p0, streams.pass_types[p0:p1],
             streams.pass_planes[p0:p1], streams.pass_nsyms[p0:p1],
             streams.pass_dists[p0:p1]))
-    if exact is not None:
-        correct_distortions(out, exact)
     return out

@@ -55,17 +55,6 @@ MESH_MIN_PIXELS = "bucketeer.mesh.min.pixels"
 # reference hardwires LOSSLESS at ImageWorkerVerticle.java:58-64; here it
 # is a default, not a constant) or "lossy".
 CONVERSION_TYPE = "bucketeer.conversion.type"
-# Tier-1 split: run EBCOT context modeling on the device and replay the
-# CX/D streams through the host MQ coder (codec/cxd.py). Truthy enables,
-# "0"/empty disables, absent defers to the BUCKETEER_DEVICE_CXD env.
-DEVICE_CXD = "bucketeer.tpu.device.cxd"
-# Full Tier-1 on device: the fused CX/D + MQ program, so the host only
-# assembles finished byte segments (codec/cxd.py run_device_mq). Truthy
-# enables, "0"/empty disables, absent defers to the BUCKETEER_DEVICE_MQ
-# env, which is off by default on every platform (on a v5e the fused
-# program is ~50x slower than the native host coder — docs/pipeline.md
-# flag table).
-DEVICE_MQ = "bucketeer.tpu.device.mq"
 # Cross-request encode scheduler (engine/scheduler.py): admission bound
 # (queued + running requests before 503), encode slots, shared host
 # Tier-1 pool size, device-batching aggregation window, and the default
@@ -77,13 +66,8 @@ SCHED_POOL_SIZE = "bucketeer.sched.pool.size"
 SCHED_WINDOW_MS = "bucketeer.sched.window.ms"
 SCHED_DEADLINE_S = "bucketeer.sched.deadline.s"
 # Device-pool data plane: worker-per-device cap (0 = every
-# jax.devices() entry), pipeline-stage mapping mode (auto | off), and
-# a fixed front-end/Tier-1 split overriding the bi-criteria mapper
-# (0 = let the mapper choose). Env analogs: BUCKETEER_SCHED_DEVICES,
-# BUCKETEER_SCHED_PIPELINE, BUCKETEER_SCHED_PIPELINE_SPLIT.
+# jax.devices() entry). Env analog: BUCKETEER_SCHED_DEVICES.
 SCHED_DEVICES = "bucketeer.sched.devices"
-SCHED_PIPELINE = "bucketeer.sched.pipeline"
-SCHED_PIPELINE_SPLIT = "bucketeer.sched.pipeline.split"
 # Decoded-image LRU cache budget for the GET /images read path, in MB
 # (converters/reader.py; 0 disables). Env analog by the standard
 # overlay: BUCKETEER_DECODE_CACHE_MB.
@@ -123,10 +107,9 @@ ALL_KEYS = (
     FILESYSTEM_CSV_MOUNT, FILESYSTEM_PREFIX, SLACK_OAUTH_TOKEN,
     SLACK_CHANNEL_ID, SLACK_ERROR_CHANNEL_ID, SLACK_WEBHOOK_URL,
     FEATURE_FLAGS, TPU_LOSSY_RATE, TPU_BATCH_SIZE, TPU_MESH_SHAPE,
-    MESH_MIN_PIXELS, CONVERSION_TYPE, DEVICE_CXD, DEVICE_MQ,
+    MESH_MIN_PIXELS, CONVERSION_TYPE,
     SCHED_QUEUE_DEPTH, SCHED_MAX_CONCURRENT, SCHED_POOL_SIZE,
-    SCHED_WINDOW_MS, SCHED_DEADLINE_S, SCHED_DEVICES, SCHED_PIPELINE,
-    SCHED_PIPELINE_SPLIT, DECODE_CACHE_MB,
+    SCHED_WINDOW_MS, SCHED_DEADLINE_S, SCHED_DEVICES, DECODE_CACHE_MB,
     JOB_JOURNAL_DIR, RETRY_MAX_ATTEMPTS, RETRY_BASE_DELAY_S,
     RETRY_MAX_DELAY_S, BREAKER_THRESHOLD, BREAKER_RESET_S,
 )

@@ -99,22 +99,12 @@ class TpuConverter:
     def __init__(self, lossy_rate: float = LOSSY_RATE,
                  jpx: bool = True,
                  mesh_min_pixels: int | None = None,
-                 device_cxd: bool | None = None,
-                 device_mq: bool | None = None,
                  scheduler=None) -> None:
         self.lossy_rate = lossy_rate
         self.jpx = jpx
         self.mesh_min_pixels = (_env_mesh_min_pixels()
                                 if mesh_min_pixels is None
                                 else mesh_min_pixels)
-        # None defers to the BUCKETEER_DEVICE_CXD env flag per encode
-        # (encoder._device_cxd); the engine wires the
-        # bucketeer.tpu.device.cxd config key through here.
-        self.device_cxd = device_cxd
-        # Full Tier-1 on device (CX/D + MQ coder); None defers to the
-        # BUCKETEER_DEVICE_MQ env flag per encode (encoder._device_mq);
-        # the engine wires bucketeer.tpu.device.mq through here.
-        self.device_mq = device_mq
         # Encodes go through the cross-request scheduler (admission
         # control + continuous device batching + shared host Tier-1).
         # None = the process-wide instance, resolved lazily per convert
@@ -180,8 +170,6 @@ class TpuConverter:
         params = EncodeParams.kakadu_recipe(
             lossless=conversion == Conversion.LOSSLESS,
             rate=self.lossy_rate)
-        params.device_cxd = self.device_cxd
-        params.device_mq = self.device_mq
         # Tiny images can't sustain 6 levels; clamp like encoders do.
         while params.levels > 1 and (min(h, w) >> params.levels) < 4:
             params.levels -= 1

@@ -70,36 +70,16 @@ class BatchConverterWorker:
         if mesh_px and hasattr(converter, "mesh_min_pixels"):
             converter.mesh_min_pixels = mesh_px
             LOG.info("mesh routing threshold set to %d pixels", mesh_px)
-        # Tier-1 split (converters/tpu.py): the config
-        # keys override the converter's env-driven defaults.
-        cxd_flag = config.get_str(cfg.DEVICE_CXD)
-        if cxd_flag is not None and hasattr(converter, "device_cxd"):
-            converter.device_cxd = cfg.truthy(cxd_flag)
-            LOG.info("device CX/D Tier-1 split %s by config",
-                     "enabled" if converter.device_cxd else "disabled")
-        mq_flag = config.get_str(cfg.DEVICE_MQ)
-        if mq_flag is not None and hasattr(converter, "device_mq"):
-            converter.device_mq = cfg.truthy(mq_flag)
-            LOG.info("full-device Tier-1 (MQ coder on device) %s by "
-                     "config",
-                     "enabled" if converter.device_mq else "disabled")
         # Device-pool data plane (engine/scheduler.py): the worker
-        # applies the pool cap and pipeline-stage mapping to whichever
-        # scheduler its converter routes through — the converter's own
-        # instance when it carries one, else the process-wide one.
+        # applies the pool cap to whichever scheduler its converter
+        # routes through — the converter's own instance when it carries
+        # one, else the process-wide one.
         sched = getattr(converter, "scheduler", None)
         if sched is None:
             from .scheduler import get_scheduler
             sched = get_scheduler()
         sched.configure(
-            devices=config.get_int(cfg.SCHED_DEVICES, 0) or None,
-            pipeline=config.get_str(cfg.SCHED_PIPELINE) or None,
-            pipeline_split=config.get_int(cfg.SCHED_PIPELINE_SPLIT, 0)
-            or None)
-        if config.get_str(cfg.SCHED_PIPELINE):
-            LOG.info("scheduler pipeline mapping %s by config "
-                     "(devices=%d, split=%d)", sched.pipeline,
-                     sched.devices, sched.pipeline_split)
+            devices=config.get_int(cfg.SCHED_DEVICES, 0) or None)
 
     def register(self, bus: MessageBus, instances: int = 2) -> None:
         bus.consumer(BATCH_CONVERTER, self.handle, instances=instances)

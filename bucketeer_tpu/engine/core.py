@@ -57,10 +57,7 @@ class Engine:
                       / 1000.0) or None,
             deadline_s=self.config.get_float(cfg.SCHED_DEADLINE_S, 0)
             or None,
-            devices=self.config.get_int(cfg.SCHED_DEVICES, 0) or None,
-            pipeline=self.config.get_str(cfg.SCHED_PIPELINE) or None,
-            pipeline_split=self.config.get_int(cfg.SCHED_PIPELINE_SPLIT,
-                                               0) or None)
+            devices=self.config.get_int(cfg.SCHED_DEVICES, 0) or None)
 
         # Unified retry policy + per-address circuit breakers
         # (engine/retry.py): one bounded backoff-with-jitter schedule

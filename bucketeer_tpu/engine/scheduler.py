@@ -6,57 +6,39 @@ Before this module every encode request ran a private pipeline:
 ``encode_array`` spun up its own one-worker executor for host Tier-1 and
 dispatched device programs with no coordination across requests, so two
 concurrent ``load_image`` calls contended for the device, serialized
-their MQ replay on single host threads, and re-paid dispatch overhead
+their host Tier-1 on single host threads, and re-paid dispatch overhead
 per chunk. The scheduler is the process-wide service that owns device
 access and host Tier-1 capacity instead:
 
 - **Device pool** — one worker thread per ``jax.devices()`` entry
   (capped by ``bucketeer.sched.devices`` / ``BUCKETEER_SCHED_DEVICES``),
-  all pulling from the one merged priority queue, so encode chunks,
-  merged tensor-codec chunks, and (in pipeline mode) fused CX/D+MQ
-  stages dispatch to whichever device is free. Workers spawn on demand:
-  a serial workload runs on device 0 exactly like the old single device
-  thread (no gratuitous per-device recompiles); backlog beyond the idle
-  workers brings the next device online. Launches stage their host
-  batch with ``jax.device_put(..., device)`` so the compiled program
-  runs on the worker's own core — committed inputs keep every
-  downstream device stage (gather, fused Tier-1) on that core instead
-  of thrashing back to device 0. On a pool of several devices a
-  front-end shape's first launch compiles (or loads) its front-end and
-  gather programs on every pool device before it returns
-  (codec/frontend._ready_on_pool): a warm-up that reaches a shape
-  reaches it everywhere, and a worker spawned later compiles nothing.
+  all pulling from the one merged priority queue, so encode chunks, merged
+  tensor-codec chunks and coefficient-dequant launches dispatch to whichever
+  device is free. Workers spawn on demand: a serial workload runs on device
+  0 exactly like the old single device thread (no gratuitous per-device
+  recompiles); backlog beyond the idle workers brings the next device
+  online. Launches stage their host batch with ``jax.device_put(...,
+  device)`` so the compiled program runs on the worker's own core —
+  committed inputs keep the downstream payload gather on that core instead
+  of thrashing back to device 0. On a pool of several devices a front-end
+  shape's first launch compiles (or loads) its front-end and gather programs
+  on every pool device before it returns (codec/frontend._ready_on_pool): a
+  warm-up that reaches a shape reaches it everywhere, and a worker spawned
+  later compiles nothing.
 - **Continuous batching** — compatible chunks from *different* requests
-  (same tile plan, mode, dtype) are concatenated into one launch,
-  padded to the existing power-of-two batch buckets (pipeline._bucket)
-  so jitted programs are reused, not retraced. Each request gets back a
-  sliced view of the merged result — per-tile results are bit-identical
-  to a solo launch because every front-end reduction is within-tile.
-  A worker only holds the aggregation window when no idle peer could
-  take arriving work instead: with free devices, parallelism beats
-  batching. Tensor-codec chunks (same dtype/row shape/backend) merge
-  the same way into one pack+MQ launch, sliced per request —
-  per-block coding is independent, so merged output is byte-identical.
-  CX/D- and device-MQ-mode chunks (``BUCKETEER_DEVICE_CXD`` /
-  ``BUCKETEER_DEVICE_MQ``) are never merged — their blockified
-  coefficients stay HBM-resident for separate device stages whose
-  programs are shaped per chunk — but they still flow through the pool.
-- **Pipeline-stage mapping** (``bucketeer.sched.pipeline=auto|off``,
-  default off) — with device MQ active, the encode pipeline has two
-  device stages: the DWT/quant front-end and the fused CX/D+MQ
-  program. In ``auto`` mode the pool is split into two disjoint device
-  subsets (front-end gets workers ``[0, k)``, Tier-1 gets ``[k, n)``)
-  joined by the same bounded queue acting as the inter-stage staging
-  buffer (depth ``BUCKETEER_SCHED_STAGE_DEPTH``, default
-  ``2*(n-k)``). The split ``k`` comes from the bi-criteria
-  throughput-vs-latency heuristic of PAPERS.md (arxiv 0801.1772):
-  minimize the pipeline period ``max(cA/k, cB/(n-k))`` first, latency
-  ``cA/k + cB/(n-k)`` second, using graftcost's modeled per-stage
-  costs (obs/cost.modeled_stage_costs); ``bucketeer.sched.pipeline.
-  split`` overrides the mapper.
-- **Shared host Tier-1** — MQ replay / packed Tier-1 runs on one pool
-  sized to host cores (``t1_encode_cxd``/``t1_encode_packed`` release
-  the GIL, proven in tests/test_native_t1.py), with per-request ordered
+  (same tile plan, dtype and tile shape) are concatenated into one launch,
+  padded to the existing power-of-two batch buckets (pipeline._bucket) so
+  jitted programs are reused, not retraced. Each request gets back a sliced
+  view of the merged result — per-tile results are bit-identical to a solo
+  launch because every front-end reduction is within-tile. A worker only
+  holds the aggregation window when no idle peer could take arriving work
+  instead: with free devices, parallelism beats batching. Tensor-codec
+  chunks (same dtype/row shape/backend) merge the same way into one pack+MQ
+  launch, sliced per request — per-block coding is independent, so merged
+  output is byte-identical.
+- **Shared host Tier-1** — packed Tier-1 runs on one pool sized to
+  host cores (``t1_encode_packed`` releases the GIL, proven in
+  tests/test_native_t1.py), with per-request ordered
   reassembly: each request collects its own futures in submission
   order, so output stays byte-identical to the serial path.
 - **Admission control** — a bounded queue with backpressure: when
@@ -78,7 +60,7 @@ Observability (``set_metrics_sink``): ``encode.queue_wait`` /
 ``decode.queue_wait`` (stages), ``encode.batch_occupancy`` /
 ``tensor.batch_occupancy`` (value distributions: requests per device
 launch), counters ``{encode,decode,tensor}.admission_rejects``,
-``{encode,tensor,t1}.device_launches`` plus the per-device
+``{encode,tensor,batchread}.device_launches`` plus the per-device
 ``....device_launches.d<N>`` split (real worker device ids — the PR 16
 placeholder that booked everything on d0 is gone),
 ``{decode,tensor}.device_assigned.d<N>`` for request-thread placement,
@@ -91,10 +73,7 @@ per-device occupancy gauge (``sched.device_occupancy.d<N>``: busy
 fraction since the pool started) and the live device-queue depth to
 ``/metrics`` reports.
 
-The pipeline-mapping trade-off this implements — shared replicated
-workers per stage versus per-request pipelines, throughput vs latency —
-is the bi-criteria mapping problem of PAPERS.md (arxiv 0801.1772);
-continuous batching on the device axis is the same shape LLM serving
+Continuous batching on the device axis is the same shape LLM serving
 stacks use.
 """
 from __future__ import annotations
@@ -114,7 +93,6 @@ import numpy as np
 
 from .. import obs
 from ..analysis.graftrace import seam
-from ..obs import cost as obs_cost
 from . import faults
 
 LOG = logging.getLogger(__name__)
@@ -197,7 +175,6 @@ class _DeviceJob:
     request whose chunks it batched through these."""
     plan: object
     tiles: np.ndarray
-    mode: str
     n_tiles: int
     ctx: object = None
     priority: int = PRIORITY_SINGLE
@@ -212,11 +189,8 @@ class _DeviceJob:
     @property
     def key(self):
         # Merge-compatibility: identical jitted program + concatenable
-        # host batch. "rows" only — cxd/mq launches are shaped per
-        # chunk (their downstream device stages bucket on realized
-        # symbol counts); mode is part of the key so they never match.
-        return (self.plan, self.mode, self.tiles.dtype.str,
-                self.tiles.shape[1:])
+        # host batch.
+        return (self.plan, self.tiles.dtype.str, self.tiles.shape[1:])
 
     @property
     def size(self) -> int:
@@ -290,29 +264,6 @@ class _DequantJob:
 
 
 @dataclass
-class _T1Job:
-    """One staged fused-CX/D+MQ launch (pipeline mode): ``fn`` is the
-    encoder's closed-over stage function, ``payload`` the HBM-resident
-    blockified coefficients, re-committed to the Tier-1 worker's device
-    before the call."""
-    fn: object
-    payload: object = None
-    ctx: object = None
-    priority: int = PRIORITY_SINGLE
-    seq: int = 0
-    event: threading.Event = field(
-        default_factory=lambda: seam.make_event("T1Job.event"))
-    result: object = None
-    error: BaseException | None = None
-
-    stage = "t1"
-
-    @property
-    def size(self) -> int:
-        return 1
-
-
-@dataclass
 class _SlicedPending:
     """A request's share of a merged front-end launch: quacks like
     frontend.PendingFrontend (resolve_stats) but resolves to a
@@ -357,12 +308,6 @@ class EncodeScheduler:
       merging.
     - ``BUCKETEER_SCHED_DEVICES`` (0 = all): device-pool size cap; the
       pool has one worker per ``jax.devices()`` entry up to the cap.
-    - ``BUCKETEER_SCHED_PIPELINE`` (off): ``auto`` maps the front-end
-      and fused-Tier-1 stages onto disjoint device subsets.
-    - ``BUCKETEER_SCHED_PIPELINE_SPLIT`` (0 = mapper): fixed front-end
-      subset size, overriding the bi-criteria mapper.
-    - ``BUCKETEER_SCHED_STAGE_DEPTH`` (0 = ``2*(n-split)``): bound on
-      staged (queued) Tier-1 launches in pipeline mode.
     - ``BUCKETEER_SCHED_DEADLINE_S`` (0 = none): default per-request
       deadline.
     - ``BUCKETEER_SCHED_RETRY_AFTER_S`` (2): the Retry-After hint
@@ -375,10 +320,7 @@ class EncodeScheduler:
                  window_s: float | None = None,
                  deadline_s: float | None = None,
                  retry_after_s: float | None = None,
-                 devices: int | None = None,
-                 pipeline: str | None = None,
-                 pipeline_split: int | None = None,
-                 stage_depth: int | None = None) -> None:
+                 devices: int | None = None) -> None:
         cores = os.cpu_count() or 2
         self.queue_depth = queue_depth if queue_depth is not None else \
             _env_int("BUCKETEER_SCHED_QUEUE_DEPTH", 32)
@@ -400,16 +342,6 @@ class EncodeScheduler:
             else _env_float("BUCKETEER_SCHED_RETRY_AFTER_S", 2.0)
         self.devices = devices if devices is not None else \
             _env_int("BUCKETEER_SCHED_DEVICES", 0)
-        self.pipeline = pipeline if pipeline is not None else \
-            (os.environ.get("BUCKETEER_SCHED_PIPELINE") or "off")
-        if self.pipeline not in ("auto", "off"):
-            raise ValueError(
-                "bucketeer.sched.pipeline must be 'auto' or 'off', "
-                f"got {self.pipeline!r}")
-        self.pipeline_split = pipeline_split if pipeline_split is not \
-            None else _env_int("BUCKETEER_SCHED_PIPELINE_SPLIT", 0)
-        self.stage_depth = stage_depth if stage_depth is not None else \
-            _env_int("BUCKETEER_SCHED_STAGE_DEPTH", 0)
 
         self._pool = ThreadPoolExecutor(max_workers=max(1, self.pool_size),
                                         thread_name_prefix="sched-t1")
@@ -431,7 +363,6 @@ class EncodeScheduler:
         self._busy_since: list = []   # launch start, None when idle
         self._inflight: list = []     # request-thread device assignments
         self._pool_t0: float | None = None
-        self._split: int | None = None      # engaged pipeline split
         self._stop = False            # device-side close flag
         # Test/graftrace seam: overrides codec.frontend.dispatch_frontend
         # so scenarios can explore the batching skeleton without JAX
@@ -464,8 +395,6 @@ class EncodeScheduler:
                 "devices": (len(self._devices)
                             if self._devices is not None else 0),
                 "device_queue_depth": len(self._djobs),
-                "pipeline": self.pipeline,
-                "pipeline_split": self._split,
             }
             if self._devices is not None and self._pool_t0 is not None:
                 elapsed = max(now - self._pool_t0, 1e-9)
@@ -484,18 +413,12 @@ class EncodeScheduler:
                   pool_size: int | None = None,
                   window_s: float | None = None,
                   deadline_s: float | None = None,
-                  devices: int | None = None,
-                  pipeline: str | None = None,
-                  pipeline_split: int | None = None) -> None:
+                  devices: int | None = None) -> None:
         """Apply deployment config (engine/core.py wires the
         ``bucketeer.sched.*`` keys through here). Resizing the pool
         swaps executors; in-flight jobs finish on the old one. The
         device cap applies to pools not yet spun up — a live pool keeps
         its resolved devices."""
-        if pipeline is not None and pipeline not in ("auto", "off"):
-            raise ValueError(
-                "bucketeer.sched.pipeline must be 'auto' or 'off', "
-                f"got {pipeline!r}")
         with self._lock:
             if queue_depth is not None and queue_depth > 0:
                 self.queue_depth = queue_depth
@@ -508,10 +431,6 @@ class EncodeScheduler:
                 self.default_deadline_s = deadline_s or None
             if devices is not None and devices >= 0:
                 self.devices = devices
-            if pipeline is not None:
-                self.pipeline = pipeline
-            if pipeline_split is not None and pipeline_split >= 0:
-                self.pipeline_split = pipeline_split
             if pool_size is not None and pool_size > 0 and \
                     pool_size != self.pool_size:
                 old = self._pool
@@ -665,15 +584,11 @@ class EncodeScheduler:
                 with t1_dec.decode_services(check=check):
                     with self._device_ctx(kind):
                         return fn(*args, **kwargs)
-            t1_launch = None
-            if self.pipeline != "off":
-                t1_launch = functools.partial(
-                    self.dispatch_t1, _priority=ticket.priority)
             with encoder_mod.pipeline_services(
                     dispatch=functools.partial(
                         self.dispatch_frontend,
                         _priority=ticket.priority),
-                    pool=self._pool, check=check, t1_launch=t1_launch):
+                    pool=self._pool, check=check):
                 return fn(*args, **kwargs)
         finally:
             self._finish(ticket)
@@ -878,7 +793,7 @@ class EncodeScheduler:
 
     # -- device batching -----------------------------------------------
 
-    def dispatch_frontend(self, plan, tiles, mode: str = "rows", *,
+    def dispatch_frontend(self, plan, tiles, *,
                           _priority: int = PRIORITY_SINGLE):
         """The encoder's device-dispatch hook: queue a front-end launch
         and block until a pool worker has dispatched it (the launch
@@ -887,7 +802,7 @@ class EncodeScheduler:
         caller gets its slice. Raises :class:`SchedulerClosed` (never
         hangs) once :meth:`close` has run."""
         self._ensure_workers()
-        job = _DeviceJob(plan, np.asarray(tiles), mode, len(tiles),
+        job = _DeviceJob(plan, np.asarray(tiles), len(tiles),
                          ctx=obs.current_context(), priority=_priority)
         with self._dq_cv:
             seam.read(self, "_stop")
@@ -965,130 +880,27 @@ class EncodeScheduler:
         seam.read(job, "result")
         return job.result
 
-    def dispatch_t1(self, fn, payload=None, *,
-                    _priority: int = PRIORITY_SINGLE):
-        """Pipeline-stage hook: run ``fn(payload)`` (the fused CX/D+MQ
-        stage) on a Tier-1-subset pool worker when the pipeline split
-        is engaged, inline on the caller otherwise. The staging queue
-        is bounded (``stage_depth``) so a fast front-end cannot pile
-        unbounded HBM-resident coefficients behind a slow Tier-1
-        subset."""
-        self._ensure_workers()
-        with self._dq_cv:
-            n = len(self._devices)
-            engaged = (self.pipeline != "off" and n >= 2
-                       and not self._stop)
-            if engaged:
-                self._engage_split_locked()
-                depth = self.stage_depth or max(2, 2 * (n - self._split))
-        if not engaged:
-            return fn(payload)
-        job = _T1Job(fn, payload, ctx=obs.current_context(),
-                     priority=_priority)
-        with self._dq_cv:
-            while True:
-                seam.read(self, "_stop")
-                if self._stop:
-                    raise SchedulerClosed(
-                        "scheduler closed while staging a Tier-1 chunk")
-                staged = sum(1 for j in self._djobs
-                             if j.stage == "t1")
-                if staged < depth:
-                    break
-                self._dq_cv.wait(0.05)
-            job.seq = next(self._dseq)
-            seam.write(self, "_djobs")
-            self._djobs.append(job)
-            self._dq_cv.notify_all()
-        job.event.wait()
-        seam.read(job, "error")
-        if job.error is not None:
-            raise job.error
-        seam.read(job, "result")
-        return job.result
-
-    def _engage_split_locked(self) -> None:
-        """First staged Tier-1 launch engages the pipeline split: pick
-        k (config override or the bi-criteria mapper), give the
-        front-end workers [0, k) and Tier-1 workers [k, n), and bring
-        the whole pool online — pipeline mode is explicit opt-in, so
-        eager spawn is the point."""
-        if self._split is not None:
-            return
-        n = len(self._devices)
-        seam.write(self, "_split")
-        self._split = self._plan_split(n)
-        LOG.info("pipeline split engaged: %d front-end / %d tier-1 "
-                 "workers over %d devices", self._split,
-                 n - self._split, n)
-        for i, t in enumerate(self._workers):
-            if t is None or not t.is_alive():
-                self._spawn_worker_locked(i)
-        self._dq_cv.notify_all()
-
-    def _plan_split(self, n: int) -> int:
-        """The bi-criteria mapper (PAPERS.md, arxiv 0801.1772): over
-        k in [1, n-1], minimize the pipeline period
-        ``max(cA/k, cB/(n-k))`` first and the latency
-        ``cA/k + cB/(n-k)`` second, with graftcost's modeled per-stage
-        seconds as cA (front-end) and cB (fused CX/D+MQ). Config
-        ``pipeline_split`` overrides; an even split is the no-model
-        fallback."""
-        if 1 <= self.pipeline_split <= n - 1:
-            return self.pipeline_split
-        costs = obs_cost.modeled_stage_costs()
-        if not costs:
-            return max(1, n // 2)
-        ca, cb = costs
-        best = None
-        for k in range(1, n):
-            cand = (max(ca / k, cb / (n - k)),
-                    ca / k + cb / (n - k), k)
-            if best is None or cand < best:
-                best = cand
-        return best[2]
-
-    def _stages_locked(self, widx: int) -> tuple:
-        """Which job stages worker ``widx`` may pull. No split: every
-        worker takes everything (a free device is a free device). Split
-        engaged: front-end workers [0, split) never touch staged Tier-1
-        work and vice versa — disjoint subsets are what makes the
-        mapping a pipeline. Merged tensor and dequant chunks ride
-        either subset."""
-        if self._split is None:
-            return ("frontend", "tensor", "dequant", "t1")
-        if widx < self._split:
-            return ("frontend", "tensor", "dequant")
-        return ("t1", "tensor", "dequant")
-
-    def _pop_job_locked(self, widx: int):
-        """Pop the highest-priority (then FIFO) queued job this worker
-        is allowed to run; None when nothing is eligible."""
-        stages = self._stages_locked(widx)
-        best = -1
-        for i, j in enumerate(self._djobs):
-            if j.stage not in stages:
-                continue
-            if best < 0 or (j.priority, j.seq) < \
-                    (self._djobs[best].priority, self._djobs[best].seq):
-                best = i
-        if best < 0:
+    def _pop_job_locked(self):
+        """Pop the highest-priority (then FIFO) queued job; None when
+        the queue is empty."""
+        if not self._djobs:
             return None
+        best = min(range(len(self._djobs)),
+                   key=lambda i: (self._djobs[i].priority,
+                                  self._djobs[i].seq))
         seam.write(self, "_djobs")
         return self._djobs.pop(best)
 
-    def _idle_peer_locked(self, widx: int, stage: str) -> bool:
-        """True when another live, idle worker could run ``stage`` jobs:
-        holding the aggregation window then is futile (the peer would
-        pop arrivals immediately) and harmful (a free device should
-        parallelize, not wait to merge)."""
+    def _idle_peer_locked(self, widx: int) -> bool:
+        """True when another live worker is idle: holding the
+        aggregation window then is futile (the peer would pop arrivals
+        immediately) and harmful (a free device should parallelize, not
+        wait to merge)."""
         seam.read(self, "_holding")
         for i, t in enumerate(self._workers):
             if i == widx or t is None or not t.is_alive():
                 continue
-            if self._busy_since[i] is None and \
-                    not self._holding[i] and \
-                    stage in self._stages_locked(i):
+            if self._busy_since[i] is None and not self._holding[i]:
                 return True
         return False
 
@@ -1100,7 +912,7 @@ class EncodeScheduler:
         size total (tiles for frontend groups, blocks for tensor,
         images for dequant)."""
         lead = group[0]
-        cap = _STAGE_CAPS.get(lead.stage, _MAX_BATCH_BLOCKS)
+        cap = _STAGE_CAPS[lead.stage]
         key = lead.key
         total = sum(j.size for j in group)
         kept: list = []
@@ -1145,26 +957,21 @@ class EncodeScheduler:
                     if self._stop:
                         self._drain_queued_locked()
                         return
-                    job = self._pop_job_locked(widx)
+                    job = self._pop_job_locked()
                     if job is not None:
                         break
                     self._dq_cv.wait()
                 seam.write(self, "_holding")
                 self._holding[widx] = True
-                # A pop frees staging-queue room: wake bounded
-                # dispatch_t1 stagers (and idle peers re-check).
+                # Idle peers re-check the queue.
                 self._dq_cv.notify_all()
                 group = [job]
-                mergeable = (job.stage in ("tensor", "dequant")
-                             or (job.stage == "frontend"
-                                 and job.mode == "rows"))
-                if mergeable and self.window_s > 0 and \
-                        not self._idle_peer_locked(widx, job.stage):
+                if self.window_s > 0 and not self._idle_peer_locked(widx):
                     # Continuous batching: wait up to the window for
                     # co-batchable chunks while other running requests
                     # could still contribute one — but only while no
                     # idle peer device could take them instead.
-                    cap = _STAGE_CAPS.get(job.stage, _MAX_BATCH_BLOCKS)
+                    cap = _STAGE_CAPS[job.stage]
                     limit = seam.monotonic() + self.window_s
                     while True:
                         total = self._take_compatible_locked(group)
@@ -1200,7 +1007,7 @@ class EncodeScheduler:
                         seam.read(self, "_stop")
                         if self._stop:
                             break
-                elif mergeable:
+                else:
                     # No window (or an idle peer): merge only what is
                     # already queued.
                     self._take_compatible_locked(group)
@@ -1212,10 +1019,8 @@ class EncodeScheduler:
                     self._launch(group, widx)
                 elif job.stage == "tensor":
                     self._launch_tensor(group, widx)
-                elif job.stage == "dequant":
-                    self._launch_dequant(group, widx)
                 else:
-                    self._launch_t1(job, widx)
+                    self._launch_dequant(group, widx)
             # The _launch* methods deliver per-job errors; anything
             # escaping is a scheduler bug (or a fatal interrupt) — log
             # it, fail the group's waiters so none hangs, and keep the
@@ -1267,21 +1072,19 @@ class EncodeScheduler:
         # it batched, carrying occupancy.
         n_tiles = sum(j.n_tiles for j in group)
         attrs = {"occupancy": len(group), "tiles": n_tiles,
-                 "mode": group[0].mode, "device_id": widx}
+                 "mode": "rows", "device_id": widx}
         links = [j.ctx for j in group if j.ctx is not None]
         completed = False
         try:
             with obs.span("device.launch", ctx=None, links=links,
                           **attrs):
                 if len(group) == 1:
-                    result = launch(
-                        group[0].plan, group[0].tiles,
-                        mode=group[0].mode)
+                    result = launch(group[0].plan, group[0].tiles)
                     seam.write(group[0], "result")
                     group[0].result = result
                 else:
                     tiles = np.concatenate([j.tiles for j in group])
-                    merged = launch(group[0].plan, tiles, mode="rows")
+                    merged = launch(group[0].plan, tiles)
                     off = 0
                     for j in group:
                         seam.write(j, "result")
@@ -1450,36 +1253,6 @@ class EncodeScheduler:
                     j.error = RuntimeError("device launch failed")
                 j.event.set()
 
-    def _launch_t1(self, job: _T1Job, widx: int) -> None:
-        """One staged fused-CX/D+MQ launch on a Tier-1-subset worker:
-        re-commit the payload to this worker's device (committed inputs
-        pin the compiled program there) and run the stage closure."""
-        dev = self._devices[widx]
-        attrs = {"occupancy": 1, "mode": "t1", "device_id": widx}
-        links = [job.ctx] if job.ctx is not None else []
-        completed = False
-        try:
-            with obs.span("device.launch", ctx=None, links=links,
-                          **attrs):
-                payload = job.payload
-                if dev is not None and payload is not None:
-                    import jax
-                    payload = jax.device_put(payload, dev)
-                seam.write(job, "result")
-                job.result = job.fn(payload)
-            completed = True
-        except Exception as exc:    # graftlint: disable=swallowed-exception
-            seam.write(job, "error")
-            job.error = exc
-        finally:
-            if self._sink is not None:
-                self._sink.count("t1.device_launches")
-                self._sink.count(f"t1.device_launches.d{widx}")
-            if not completed and job.error is None:
-                seam.write(job, "error")
-                job.error = RuntimeError("device launch failed")
-            job.event.set()
-
     # -- lifecycle -----------------------------------------------------
 
     def close(self) -> None:
@@ -1546,8 +1319,6 @@ class EncodeScheduler:
                               if self._devices is not None
                               else self.devices)
             out["device_queue_depth"] = len(self._djobs)
-            out["pipeline"] = self.pipeline
-            out["pipeline_split"] = self._split
         return out
 
 

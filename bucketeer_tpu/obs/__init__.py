@@ -20,14 +20,12 @@ surface:
 - :func:`stage` — a span that also feeds its ``/metrics`` stage as it
   closes: the one seam for a timed layer boundary; :func:`waited` the
   same for a wait that began on another thread.
-- :mod:`.cost` — graftcost-modeled stage costs for the scheduler's
-  pipeline mapper.
 
 docs/observability.md is the operator-facing walkthrough.
 """
 from __future__ import annotations
 
-from . import cost, export, flight, logctx, slo  # noqa: F401
+from . import export, flight, logctx, slo  # noqa: F401
 from .slo import SloWatchdog  # noqa: F401
 from .trace import (LAYER_SPANS, Recorder, bind,  # noqa: F401
                     current_context, current_request_id, get_recorder,

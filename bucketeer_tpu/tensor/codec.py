@@ -5,7 +5,7 @@ through the image pipeline's own Tier-1 kernels.
 (tensor/planes.py), carves them into the same 64x64 code-blocks the
 image front-end uses, and routes them through the device CX/D
 context-modeling scan chained into the device MQ arithmetic coder
-(codec/cxd.py, the ``BUCKETEER_DEVICE_MQ`` machinery of PR 9) — the
+(codec/cxd.py, :func:`~bucketeer_tpu.codec.cxd.run_device_mq`) — the
 host never touches a symbol; it assembles finished byte segments into
 the self-describing ``BTT1`` container (tensor/container.py). This is
 the "RD-optimized trit-plane latent coding" shape from PAPERS.md

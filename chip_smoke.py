@@ -403,8 +403,6 @@ def _tier1(stages: dict) -> str:
     _check(t1_batch.python_coded_blocks == 0,
            f"{t1_batch.python_coded_blocks} code-blocks took the "
            "pure-Python Tier-1 coder")
-    if "encode.mq_device" in stages:
-        return "device fused (cxd.fused_program, jnp)"
     _check(native.available(), "native Tier-1 library did not load")
     return "host native"
 

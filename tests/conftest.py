@@ -4,7 +4,8 @@ The reference gates its hardware-dependent tests (Kakadu) behind runtime
 probes (reference: src/test/java/.../converters/KakaduConverterTest.java:97-115).
 We do the analog for TPUs: tests always run on a virtual 8-device CPU
 platform so sharding logic is exercised without real chips; the chip
-runs are ``chip_smoke.py`` (and bench.py) through the chip tool.
+runs are ``chip_smoke.py`` and the ``perfbench/`` benchmark on a TPU
+host.
 """
 import os
 

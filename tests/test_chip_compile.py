@@ -53,8 +53,7 @@ def _compile(fn, shapes, sharding):
 
 def test_frontend_rows_512_rgb_l6_compiles_for_v5e(one_chip):
     plan = make_plan(512, 512, 3, 6, False, 8)
-    fn, _ = frontend.frontend_program(plan, frontend.layout_for(plan).P,
-                                      "rows")
+    fn, _ = frontend.frontend_program(plan, frontend.layout_for(plan).P)
     compiled = _compile(fn, [((8, 512, 512, 3), jnp.int32)], one_chip)
     assert compiled.memory_analysis().argument_size_in_bytes == \
         8 * 512 * 512 * 3 * 4

@@ -6,11 +6,9 @@ band classes, all three passes, the run-length shortcut, sign coding,
 partial blocks and bit-plane floors — so replaying the stream through
 the host MQ coder (native t1_encode_cxd or the Python fallback) yields
 byte-identical block data, identical truncation points, and
-bit-identical distortion values. On top of that, end-to-end encodes
-with BUCKETEER_DEVICE_CXD must be byte-identical to the legacy packed
-path.
+bit-identical distortion values. The tensor codec's ``replay`` backend
+runs this path; image encodes take the packed host coder.
 """
-import dataclasses
 from functools import partial
 
 import jax
@@ -19,9 +17,7 @@ import numpy as np
 import pytest
 
 from bucketeer_tpu import native
-from bucketeer_tpu.codec import cxd, encoder, rate as rate_mod, t1_batch
-from bucketeer_tpu.codec.encoder import EncodeParams
-from bucketeer_tpu.server.metrics import Metrics
+from bucketeer_tpu.codec import cxd, rate as rate_mod, t1_batch
 
 P_TEST = 5          # one compiled scan shared by every unit trial
 
@@ -262,47 +258,6 @@ def test_pallas_kernel_matches_jnp_scan(rng):
     got = [np.asarray(a) for a in cxd_pallas(L, *args, interpret=True)]
     for g, r in zip(got, ref):
         np.testing.assert_array_equal(g, r)
-
-
-def test_e2e_lossless_byte_identical(rng):
-    img = _photo(rng, 64, 64)
-    params = EncodeParams(lossless=True, levels=2)
-    legacy = encoder.encode_jp2(
-        img, 8, dataclasses.replace(params, device_cxd=False))
-    split = encoder.encode_jp2(
-        img, 8, dataclasses.replace(params, device_cxd=True))
-    assert legacy == split
-
-
-def test_e2e_rate_target_byte_identical_env_flag(rng, monkeypatch):
-    """Rate-targeted lossy (floors, PCRD, margin retries) through the
-    env flag: distortion parity must hold or layers shift."""
-    img = _photo(rng, 64, 64, comps=3)
-    params = EncodeParams(lossless=False, levels=2, rate=1.5,
-                          n_layers=3, base_delta=0.5)
-    monkeypatch.delenv("BUCKETEER_DEVICE_CXD", raising=False)
-    legacy = encoder.encode_jp2(img, 8, params)
-    monkeypatch.setenv("BUCKETEER_DEVICE_CXD", "1")
-    sink = Metrics()
-    encoder.set_metrics_sink(sink)
-    try:
-        split = encoder.encode_jp2(img, 8, params)
-    finally:
-        encoder.set_metrics_sink(None)
-    assert legacy == split
-    report = sink.report()
-    assert "encode.cxd_device" in report["stages"]
-    mq = report["stages"]["encode.mq_replay"]
-    assert mq["items"] > 0                      # symbols/s observable
-    assert report["counters"]["encode.cxd_symbols"] == mq["items"]
-
-
-def _photo(rng, h, w, comps=1):
-    y, x = np.mgrid[0:h, 0:w]
-    base = 120 + 80 * np.sin(x / 17.0) * np.cos(y / 13.0)
-    img = base[..., None] + rng.normal(0, 8, (h, w, comps))
-    img = np.clip(img, 0, 255).astype(np.uint8)
-    return img[..., 0] if comps == 1 else img
 
 
 # --- floor estimator regression (ADVICE r5 #4) --------------------------

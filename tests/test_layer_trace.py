@@ -6,6 +6,7 @@ benchmark's readers of those stages (``perfbench/metrics``)."""
 import glob
 import importlib.util
 import os
+import re
 
 import jax
 import numpy as np
@@ -154,6 +155,53 @@ def test_t1_wait_records_each_chunk_and_counts_pixels_once(
     assert sorted(s["attrs"]["pixels"] for s in spans) == \
         [0] * chunks + [2 * 64 * 64] * chunks
     assert "encode.t1_wait" not in obs.LAYER_SPANS
+
+
+def _stages_read(prefix: str) -> set:
+    """The stage names starting with ``prefix`` that the benchmark's
+    metric readers read."""
+    names = set()
+    for path in glob.glob(os.path.join(METRICS_DIR, "*.py")):
+        with open(path, encoding="utf-8") as fh:
+            names.update(re.findall(r'stage\(ctx, "([^"]+)"\)', fh.read()))
+    return {n for n in names if n.startswith(prefix)}
+
+
+# Encode stages /metrics shows that no benchmark reader reads.
+UNREAD_ENCODE_STAGES = {"encode.device_dispatch", "encode.request"}
+
+
+@pytest.mark.parametrize("fields", [
+    {"lossless": True, "levels": 3},
+    {"lossless": False, "levels": 3, "rate": 2.0, "n_layers": 3,
+     "base_delta": 0.5},
+], ids=["streaming_lossless", "rate_targeted"])
+def test_encode_records_the_stages_ingest_reads(rng, sink, monkeypatch,
+                                                fields):
+    """An encode through the scheduler records exactly the encode.*
+    stages the ingest readers read, besides the two no reader reads:
+    every Tier-1 chunk takes the one host path, and no other path's
+    stages appear."""
+    monkeypatch.setenv("BUCKETEER_OVERLAP_TILES", "2")
+    sched = EncodeScheduler(window_s=0.0)
+    sched.set_metrics_sink(sink)
+    img = _photo(rng, 128, 128)
+    try:
+        data = sched.encode_jp2(img, 8, EncodeParams(tile_size=64,
+                                                     **fields))
+    finally:
+        sched.close()
+    assert decode(data).shape == img.shape
+    recorded = {n for n in sink.stages if n.startswith("encode.")}
+    read = _stages_read("encode.")
+    assert read >= {"encode.host_code", "encode.fetch_payload",
+                    "encode.tier2", "encode.t1_wait", "encode.queue_wait"}
+    assert recorded == read | UNREAD_ENCODE_STAGES
+    for name in ("encode.host_code", "encode.fetch_payload",
+                 "encode.tier2"):
+        assert sink.stages[name].pixels == 128 * 128, name
+    assert sink.stages["t1.native"].count == \
+        sink.stages["encode.host_code"].count
 
 
 def test_decode_records_mq_once_per_tile(rng, sink):

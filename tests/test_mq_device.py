@@ -20,12 +20,10 @@ The contract under test, layered:
 3. **Kernel parity** — the Pallas MQ kernel (interpret mode on CPU)
    equals the vmapped ``lax.scan`` path bit for bit; on a real TPU the
    compiled kernels are refused by Mosaic (an expected failure).
-4. **End to end** — ``BUCKETEER_DEVICE_MQ`` encodes byte-identical
-   files to the host-MQ path (lossless gray, rate-targeted RGB, 16-bit,
-   multi-tile) and reports the encode.mq_device /
-   encode.t1_device_total segments.
+
+The tensor codec's ``device`` backend runs this chain; image encodes
+take the packed host coder.
 """
-import dataclasses
 from functools import lru_cache, partial
 
 import jax
@@ -33,10 +31,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bucketeer_tpu.codec import cxd, encoder, rate as rate_mod, t1_batch
-from bucketeer_tpu.codec.encoder import EncodeParams
+from bucketeer_tpu.codec import cxd, rate as rate_mod, t1_batch
 from bucketeer_tpu.codec.mq import MQEncoder
-from bucketeer_tpu.server.metrics import Metrics
 
 P_TEST = 5
 
@@ -274,76 +270,6 @@ def test_fused_pallas_interpret_matches_jnp(rng):
                                       err_msg=f"output {k}")
 
 
-def test_e2e_device_mq_byte_identical_lossless(rng):
-    img = _photo(rng, 64, 64)
-    params = EncodeParams(lossless=True, levels=2)
-    legacy = encoder.encode_jp2(
-        img, 8, dataclasses.replace(params, device_cxd=False,
-                                    device_mq=False))
-    split = encoder.encode_jp2(
-        img, 8, dataclasses.replace(params, device_mq=True))
-    assert legacy == split
-
-
-def test_e2e_device_mq_rate_target_env_flag(rng, monkeypatch):
-    """Rate-targeted lossy (floors, PCRD, margin retries) through the
-    env flag, with the new /metrics segments asserted: distortion and
-    truncation parity must hold or layers shift."""
-    img = _photo(rng, 64, 64, comps=3)
-    params = EncodeParams(lossless=False, levels=2, rate=1.5,
-                          n_layers=3, base_delta=0.5)
-    monkeypatch.delenv("BUCKETEER_DEVICE_MQ", raising=False)
-    monkeypatch.delenv("BUCKETEER_DEVICE_CXD", raising=False)
-    legacy = encoder.encode_jp2(img, 8, params)
-    monkeypatch.setenv("BUCKETEER_DEVICE_MQ", "1")
-    sink = Metrics()
-    encoder.set_metrics_sink(sink)
-    try:
-        split = encoder.encode_jp2(img, 8, params)
-    finally:
-        encoder.set_metrics_sink(None)
-    assert legacy == split
-    st = sink.report()["stages"]
-    assert "encode.cxd_device" in st
-    assert "encode.mq_replay" not in st     # host replay never ran
-    assert st["encode.mq_device"]["items"] > 0          # bytes
-    assert st["encode.t1_device_total"]["items"] > 0    # symbols
-    counters = sink.report()["counters"]
-    assert counters["encode.mq_device_bytes"] == \
-        st["encode.mq_device"]["items"]
-
-
-def test_e2e_device_mq_multitile(rng):
-    """A multi-tile grid (the chunked pipeline, several chunks each
-    assembling several blocks) through the device-MQ path."""
-    img8 = _photo(rng, 96, 64)
-    params8 = EncodeParams(lossless=True, levels=2, tile_size=64)
-    legacy = encoder.encode_jp2(
-        img8, 8, dataclasses.replace(params8, device_cxd=False,
-                                     device_mq=False))
-    split = encoder.encode_jp2(
-        img8, 8, dataclasses.replace(params8, device_mq=True))
-    assert legacy == split
-
-
-@pytest.mark.slow
-def test_e2e_device_mq_16bit(rng):
-    """16-bit lossless through the device-MQ path. Slow-marked: the
-    16-bit level shift puts ~15 planes in play whatever the content,
-    and the jnp scans pay ~a minute of CPU for that (the TPU kernels
-    don't care)."""
-    y, x = np.mgrid[0:64, 0:64]
-    img16 = (600 + 380 * np.sin(x / 9.0) * np.cos(y / 7.0)
-             + rng.normal(0, 12, (64, 64))).astype(np.uint16)
-    params16 = EncodeParams(lossless=True, levels=2)
-    legacy = encoder.encode_jp2(
-        img16, 16, dataclasses.replace(params16, device_cxd=False,
-                                       device_mq=False))
-    split = encoder.encode_jp2(
-        img16, 16, dataclasses.replace(params16, device_mq=True))
-    assert legacy == split
-
-
 def test_explicit_pallas_raises_with_mosaic_reason(monkeypatch):
     """BUCKETEER_CXD_PALLAS=1 asks for compiled kernels no backend
     lowers: program build raises and names the Mosaic refusal — no
@@ -403,65 +329,3 @@ def test_compiled_kernels_match_jnp_on_tpu(rng):
     fgot = fused_pallas(P_TEST, *args)
     for g, r in zip(fgot, fref):
         np.testing.assert_array_equal(np.asarray(g), np.asarray(r))
-
-
-@pytest.mark.slow
-def test_device_mq_host_work_reduction(rng):
-    """Throughput smoke on a bench-recipe-shaped encode: the host's
-    Tier-1 share in device-MQ mode (block assembly) must be >= 5x
-    smaller than MQ-replay mode's host share (the ISSUE 9 acceptance
-    bar), and on a real accelerator the device-MQ wall clock must not
-    lose to replay."""
-    import time
-
-    from bucketeer_tpu.codec import cxd as cxd_mod
-
-    img = _photo(rng, 128, 128, comps=3)
-    params = EncodeParams(lossless=False, levels=3, rate=3.0,
-                          n_layers=3, base_delta=2.0)
-
-    def timed_host(mode_params, mod, name):
-        """(re-timed host Tier-1 seconds, encode wall seconds) with the
-        host share captured through the named module seam."""
-        calls = []
-        orig = getattr(mod, name)
-
-        def cap(*args):
-            calls.append(args)
-            return orig(*args)
-
-        encoder.encode_jp2(img, 8, mode_params)     # warm
-        setattr(mod, name, cap)
-        try:
-            t0 = time.perf_counter()
-            encoder.encode_jp2(img, 8, mode_params)
-            wall = time.perf_counter() - t0
-        finally:
-            setattr(mod, name, orig)
-        best = float("inf")
-        for _ in range(3):
-            t0 = time.perf_counter()
-            for args in calls:
-                orig(*args)
-            best = min(best, time.perf_counter() - t0)
-        return best, wall
-
-    replay_s, replay_wall = timed_host(
-        dataclasses.replace(params, device_cxd=True, device_mq=False),
-        t1_batch, "encode_cxd")
-    mq_s, mq_wall = timed_host(
-        dataclasses.replace(params, device_mq=True),
-        cxd_mod, "assemble_mq_blocks")
-    assert mq_s * 5 <= replay_s, (
-        f"device-MQ host share {mq_s:.4f}s not >=5x below replay's "
-        f"{replay_s:.4f}s")
-    if jax.default_backend() == "tpu":
-        assert mq_wall <= replay_wall * 1.05
-
-
-def _photo(rng, h, w, comps=1):
-    y, x = np.mgrid[0:h, 0:w]
-    base = 120 + 80 * np.sin(x / 17.0) * np.cos(y / 13.0)
-    img = base[..., None] + rng.normal(0, 8, (h, w, comps))
-    img = np.clip(img, 0, 255).astype(np.uint8)
-    return img[..., 0] if comps == 1 else img

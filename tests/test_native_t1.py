@@ -10,7 +10,9 @@ import numpy as np
 import pytest
 
 from bucketeer_tpu import native
-from bucketeer_tpu.codec import t1, t1_batch
+from bucketeer_tpu.codec import encoder, rate as rate_mod, t1, t1_batch
+from bucketeer_tpu.codec.encoder import EncodeParams
+from bucketeer_tpu.server.metrics import Metrics
 
 pytestmark = pytest.mark.skipif(not native.available(),
                                 reason="native T1 unavailable (no g++?)")
@@ -91,6 +93,84 @@ def test_concurrent_first_load_waits_for_the_build(tmp_path, monkeypatch):
     assert not any(t.is_alive() for t in threads)
     assert len(got) == 8 and got[0] is not None
     assert all(lib is got[0] for lib in got)
+
+
+def _photo(rng, shape, bitdepth):
+    """A smooth image with sensor noise at ``bitdepth`` bits."""
+    h, w = shape[:2]
+    y, x = np.mgrid[0:h, 0:w]
+    scale = 1 << (bitdepth - 8)
+    base = (120 + 80 * np.sin(x / 17.0) * np.cos(y / 13.0)) * scale
+    if len(shape) == 3:
+        base = base[..., None]
+    img = base + rng.normal(0, 8 * scale, shape)
+    dtype = np.uint8 if bitdepth == 8 else np.uint16
+    return np.clip(img, 0, (1 << bitdepth) - 1).astype(dtype)
+
+
+def _starve_first_floors(monkeypatch):
+    """Floors at every block's top plane on an encode's first estimate:
+    they code nothing, so the encode reruns its floors."""
+    real = rate_mod.estimate_floors
+    calls = []
+
+    def starve_first(nbps, *args, **kwargs):
+        floors, lam = real(nbps, *args, **kwargs)
+        calls.append(None)
+        if len(calls) == 1:
+            floors = np.asarray(nbps, dtype=floors.dtype).copy()
+        return floors, lam
+
+    monkeypatch.setattr(rate_mod, "estimate_floors", starve_first)
+
+
+# (image shape, bit depth, EncodeParams fields, force a floor rerun)
+_WHOLE_ENCODES = {
+    "gray8_lossless": ((64, 64), 8, {"lossless": True, "levels": 2},
+                       False),
+    "rgb8_rate_floor_rerun": ((64, 64, 3), 8,
+                              {"lossless": False, "levels": 2,
+                               "rate": 1.5, "n_layers": 3,
+                               "base_delta": 0.5}, True),
+    "gray8_multitile": ((96, 64), 8,
+                        {"lossless": True, "levels": 2, "tile_size": 64},
+                        False),
+    "gray16_lossless": ((64, 64), 16, {"lossless": True, "levels": 2},
+                        False),
+    "gray16_rate": ((64, 64), 16,
+                    {"lossless": False, "levels": 2, "rate": 2.0,
+                     "n_layers": 3, "base_delta": 0.5 * 256}, False),
+}
+
+
+@pytest.mark.parametrize("case", list(_WHOLE_ENCODES))
+def test_whole_encode_matches_python_coder(case, rng, monkeypatch):
+    """A whole encode through the native host coder — the packed
+    payload, the thread pool, the distortion correction and PCRD —
+    writes the same file as the same encode through the pure-Python
+    reference coder."""
+    shape, bitdepth, fields, rerun = _WHOLE_ENCODES[case]
+    img = _photo(rng, shape, bitdepth)
+    params = EncodeParams(**fields)
+
+    def encode():
+        sink = Metrics()
+        monkeypatch.setattr(encoder, "_metrics_sink", sink)
+        if rerun:
+            _starve_first_floors(monkeypatch)
+        data = encoder.encode_jp2(img, bitdepth, params)
+        return data, sink
+
+    got, got_sink = encode()
+    assert t1_batch.last_native_call["fn"] == "t1_encode_packed"
+    before = t1_batch.python_coded_blocks
+    monkeypatch.setattr(native, "load", lambda: None)
+    ref, ref_sink = encode()
+    assert t1_batch.python_coded_blocks > before
+    assert got == ref
+    reruns = 1 if rerun else 0
+    assert got_sink.counters["encode.floor_reruns"] == reruns
+    assert ref_sink.counters["encode.floor_reruns"] == reruns
 
 
 def _dense_specs(rng, n):

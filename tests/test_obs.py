@@ -2,7 +2,7 @@
 path, ring accounting, context propagation
 (threads, bind), the flight recorder's dumps and rate limiting,
 Chrome-trace export validity, log-record correlation, the SLO
-watchdog, the modeled stage costs, the histogram math behind the new
+watchdog, the histogram math behind the new
 server-side percentiles, and the Prometheus exposition round-trip."""
 import json
 import logging
@@ -14,7 +14,6 @@ import numpy as np
 import pytest
 
 from bucketeer_tpu import obs
-from bucketeer_tpu.obs import cost as obs_cost
 from bucketeer_tpu.obs import logctx
 from bucketeer_tpu.obs.trace import _NOOP, Recorder
 from bucketeer_tpu.server.metrics import LatencyHist, Metrics
@@ -326,30 +325,6 @@ def test_slo_breach_counts_and_dumps_flight(recorder):
     assert dumps[-1]["request_id"] == "slow-req"
     # Unknown endpoint with no default: never a breach.
     assert watchdog.observe("other", 99.0) is False
-
-
-# --- modeled stage costs ---------------------------------------------------
-
-def test_unknown_device_kind_raises_in_cost_model(monkeypatch):
-    """The machine model is keyed by the device_kind JAX reports: a
-    v5e gets its own entry, and an unknown accelerator raises when a
-    modeled stage cost is asked for instead of borrowing another
-    chip's."""
-    import types
-
-    import jax
-
-    from bucketeer_tpu.analysis import graftcost
-
-    assert graftcost.machine_for_device_kind("TPU v5 lite").name == \
-        "tpu_v5e"
-    with pytest.raises(graftcost.UnknownDevice, match="TPU v99"):
-        graftcost.machine_for_device_kind("TPU v99")
-    with monkeypatch.context() as m:
-        m.setattr(jax, "devices",
-                  lambda: [types.SimpleNamespace(device_kind="TPU v99")])
-        with pytest.raises(graftcost.UnknownDevice):
-            obs_cost.modeled_stage_costs()
 
 
 # --- histogram math -------------------------------------------------------

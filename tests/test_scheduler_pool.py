@@ -2,9 +2,8 @@
 multi-device scheduler must spread launches across pool workers with
 exact per-device attribution, stay byte-identical to the serialized
 single-device path for every job kind (encode / decode / tensor), size
-itself from config/env, keep admission control intact with N workers,
-and map pipeline stages onto disjoint device subsets via the
-bi-criteria splitter. Runs on the conftest-forced 8-device CPU mesh."""
+itself from config/env, and keep admission control intact with N
+workers. Runs on the conftest-forced 8-device CPU mesh."""
 import threading
 import time
 
@@ -22,9 +21,10 @@ from bucketeer_tpu.tensor import decode_tensor, encode_tensor
 JOIN_S = 10
 
 
-def _images(n, size, seed):
+def _images(n, size, seed, bitdepth=8):
     rng = np.random.default_rng(seed)
-    return [rng.integers(0, 255, (size, size, 3), dtype=np.uint8)
+    dtype = np.uint8 if bitdepth == 8 else np.uint16
+    return [rng.integers(0, 1 << bitdepth, (size, size, 3), dtype=dtype)
             for _ in range(n)]
 
 
@@ -132,12 +132,20 @@ def test_broken_backend_raises_instead_of_deviceless_worker(monkeypatch):
         s.close()
 
 
-def test_pool_encode_bytes_identical(sched):
-    imgs = _images(4, 64, seed=21)
-    params = EncodeParams(lossless=True, levels=3)
-    serial = [encoder.encode_jp2(im, 8, params) for im in imgs]
+_LOSSLESS = {"lossless": True, "levels": 3}
+_RATE = {"lossless": False, "levels": 3, "base_delta": 2.0, "rate": 1.5}
+
+
+@pytest.mark.parametrize("bitdepth,kw", [(8, _LOSSLESS), (8, _RATE),
+                                         (16, _LOSSLESS)],
+                         ids=["lossless8", "rate8", "lossless16"])
+def test_pool_encode_bytes_identical(sched, bitdepth, kw):
+    imgs = _images(4, 64, seed=21, bitdepth=bitdepth)
+    params = EncodeParams(**kw)
+    serial = [encoder.encode_jp2(im, bitdepth, params) for im in imgs]
     outs, errs = _run_concurrent(
-        [lambda im=im: sched.encode_jp2(im, 8, params) for im in imgs])
+        [lambda im=im: sched.encode_jp2(im, bitdepth, params)
+         for im in imgs])
     assert errs == [None] * 4
     assert outs == serial
 
@@ -180,125 +188,6 @@ def test_pool_tensor_bytes_identical():
         sched.close()
 
 
-@pytest.mark.slow
-def test_pool_rate_targeted_cxd_bytes_identical(sched):
-    """The fused-path corner of the matrix: rate-targeted encodes with
-    the device CX/D scan, concurrent over the pool, byte-identical to
-    the serialized baseline (compiles the device scan: slow-marked;
-    the serving-stress CI job runs it)."""
-    imgs = _images(3, 96, seed=24)
-    params = EncodeParams(lossless=False, levels=3, base_delta=2.0,
-                          rate=1.5, device_cxd=True)
-    serial = [encoder.encode_jp2(im, 8, params) for im in imgs]
-    outs, errs = _run_concurrent(
-        [lambda im=im: sched.encode_jp2(im, 8, params) for im in imgs])
-    assert errs == [None] * 3
-    assert outs == serial
-
-
-@pytest.mark.slow
-def test_pipeline_auto_bytes_identical():
-    """pipeline=auto with the fused device MQ path: front-end and
-    Tier-1 stages run on disjoint device subsets, output byte-identical
-    to the in-process single-device encoder."""
-    sched = EncodeScheduler(queue_depth=16, max_concurrent=4,
-                            pool_size=2, window_s=0.2, pipeline="auto")
-    imgs = _images(3, 64, seed=25)
-    params = EncodeParams(lossless=True, levels=2, device_cxd=True,
-                          device_mq=True)
-    try:
-        serial = [encoder.encode_jp2(im, 8, params) for im in imgs]
-        outs, errs = _run_concurrent(
-            [lambda im=im: sched.encode_jp2(im, 8, params)
-             for im in imgs])
-        assert errs == [None] * 3
-        assert outs == serial
-        assert sched.stats()["pipeline_split"] is not None
-    finally:
-        sched.close()
-
-
-# --- pipeline-stage mapping ------------------------------------------
-
-def test_dispatch_t1_stages_onto_tier1_subset():
-    """With pipeline=auto over a simulated 4-device pool, staged Tier-1
-    closures run on pool workers from the Tier-1 subset only (worker
-    index >= split), with per-device attribution."""
-    sched = EncodeScheduler(queue_depth=16, max_concurrent=4,
-                            pool_size=2, window_s=0, devices=4,
-                            pipeline="auto", pipeline_split=2)
-    sched.launch_fn = lambda plan, tiles, mode="rows": "pending"
-    sink = Metrics()
-    sched.set_metrics_sink(sink)
-    try:
-        outs, errs = _run_concurrent(
-            [lambda i=i: sched.dispatch_t1(lambda p: ("ran", p), i)
-             for i in range(4)])
-        assert errs == [None] * 4
-        assert sorted(outs) == [("ran", i) for i in range(4)]
-        assert sched.stats()["pipeline_split"] == 2
-        counters = sink.report()["counters"]
-        per_dev = _per_device(counters, "t1")
-        assert counters["t1.device_launches"] == 4
-        assert sum(per_dev.values()) == 4
-        # Disjoint subsets: Tier-1 work never lands on a front-end
-        # worker [0, split).
-        assert all(int(k.rsplit(".d", 1)[1]) >= 2 for k in per_dev), \
-            per_dev
-    finally:
-        sched.close()
-
-
-def test_dispatch_t1_pipeline_off_runs_inline():
-    sched = EncodeScheduler(queue_depth=4, max_concurrent=2,
-                            pool_size=1, window_s=0, devices=4)
-    sched.launch_fn = lambda plan, tiles, mode="rows": "pending"
-    sink = Metrics()
-    sched.set_metrics_sink(sink)
-    try:
-        assert sched.dispatch_t1(lambda p: p + 1, 41) == 42
-        counters = sink.report().get("counters", {})
-        assert "t1.device_launches" not in counters
-        assert sched.stats()["pipeline_split"] is None
-    finally:
-        sched.close()
-
-
-def test_plan_split_override_model_and_fallback(monkeypatch):
-    from bucketeer_tpu.obs import cost as obs_cost
-
-    sched = EncodeScheduler(pipeline="auto", pipeline_split=3)
-    try:
-        assert sched._plan_split(8) == 3          # config override wins
-        sched.pipeline_split = 0
-        # Bi-criteria mapper on modeled costs: heavy Tier-1 stage pulls
-        # the split toward more Tier-1 workers.
-        monkeypatch.setattr(obs_cost, "modeled_stage_costs",
-                            lambda: (3.0, 1.0))
-        assert sched._plan_split(4) == 3
-        monkeypatch.setattr(obs_cost, "modeled_stage_costs",
-                            lambda: (1.0, 1.0))
-        assert sched._plan_split(4) == 2
-        # No model: even split.
-        monkeypatch.setattr(obs_cost, "modeled_stage_costs",
-                            lambda: None)
-        assert sched._plan_split(8) == 4
-    finally:
-        sched.close()
-
-
-def test_modeled_stage_costs_from_manifest():
-    """The repo manifest + CPU machine model yield both stage costs
-    (the mapper's inputs) as positive seconds."""
-    from bucketeer_tpu.obs import cost as obs_cost
-
-    costs = obs_cost.modeled_stage_costs()
-    if costs is None:
-        pytest.skip("no audit manifest/machine model available")
-    ca, cb = costs
-    assert ca > 0 and cb > 0
-
-
 # --- pool sizing and config ------------------------------------------
 
 def test_devices_env_and_ctor_sizing(monkeypatch):
@@ -328,16 +217,11 @@ def test_devices_cap_clamps_to_available():
         sched.close()
 
 
-def test_invalid_pipeline_rejected():
-    with pytest.raises(ValueError):
-        EncodeScheduler(pipeline="sideways")
+def test_configure_devices():
     sched = EncodeScheduler()
     try:
-        with pytest.raises(ValueError):
-            sched.configure(pipeline="sideways")
-        sched.configure(pipeline="auto", devices=2, pipeline_split=1)
-        assert (sched.pipeline, sched.devices,
-                sched.pipeline_split) == ("auto", 2, 1)
+        sched.configure(devices=2)
+        assert sched.devices == 2
     finally:
         sched.close()
 

@@ -162,7 +162,8 @@ def test_flat_collect_matches_scalar_reference(lossy, with_floor,
 
 
 def test_correcting_built_blocks_matches_scalar_reference():
-    """The device-MQ path corrects blocks that are already objects."""
+    """The Python fallback of encode_packed corrects blocks that are
+    already objects."""
     rng = np.random.default_rng(2727)
     n = 40
     chunk = _packed_chunk(rng, n, with_floor=True)
